@@ -9,7 +9,9 @@ implementation-level substitution the paper makes inside HNSW/FAISS/NGT.
 
 Convention: all ``*_scores`` functions are batched [Q, d] x [N, d] -> [Q, N]
 and return *larger-is-closer* scores (inner product; negated L2) so that a
-single top-k applies to every metric.
+single top-k applies to every metric.  Float dots run at ``HIGHEST``
+precision: a TPU's default f32 dot rounds its inputs to bf16, which an
+fp32 arm or an exact rerank pass must not do.
 """
 
 from __future__ import annotations
@@ -21,6 +23,8 @@ import jax.numpy as jnp
 
 Metric = str  # "ip" | "l2" | "angular"
 
+_F32 = jax.lax.Precision.HIGHEST
+
 _VALID_METRICS = ("ip", "l2", "angular")
 
 
@@ -30,7 +34,8 @@ _VALID_METRICS = ("ip", "l2", "angular")
 
 def ip_scores(q: jax.Array, x: jax.Array) -> jax.Array:
     """Maximum-inner-product scores, [Q, N] f32."""
-    return jnp.dot(q.astype(jnp.float32), x.astype(jnp.float32).T)
+    return jnp.dot(q.astype(jnp.float32), x.astype(jnp.float32).T,
+                   precision=_F32)
 
 
 def l2_scores(q: jax.Array, x: jax.Array) -> jax.Array:
@@ -39,7 +44,7 @@ def l2_scores(q: jax.Array, x: jax.Array) -> jax.Array:
     x = x.astype(jnp.float32)
     qq = jnp.sum(q * q, axis=-1, keepdims=True)          # [Q, 1]
     xx = jnp.sum(x * x, axis=-1)[None, :]                # [1, N]
-    return -(qq + xx - 2.0 * jnp.dot(q, x.T))
+    return -(qq + xx - 2.0 * jnp.dot(q, x.T, precision=_F32))
 
 
 def angular_scores(q: jax.Array, x: jax.Array) -> jax.Array:
@@ -48,7 +53,7 @@ def angular_scores(q: jax.Array, x: jax.Array) -> jax.Array:
     x = x.astype(jnp.float32)
     qn = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
     xn = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-12)
-    return jnp.dot(qn, xn.T)
+    return jnp.dot(qn, xn.T, precision=_F32)
 
 
 # --------------------------------------------------------------------------
@@ -133,7 +138,8 @@ def _bmm(q: jax.Array, rows: jax.Array) -> jax.Array:
     their unsharded twins (a vmapped [1, d] x [d, W] dot picks a
     different f32 accumulation order under ``shard_map``)."""
     return jnp.einsum(
-        "qd,qwd->qw", q.astype(jnp.float32), rows.astype(jnp.float32)
+        "qd,qwd->qw", q.astype(jnp.float32), rows.astype(jnp.float32),
+        precision=_F32,
     )
 
 

@@ -1,7 +1,6 @@
-# Distribution utilities: mesh-sharding rules for every model family, a
-# shard_map compatibility shim (jax moved shard_map out of experimental
-# across the versions this repo supports), placement plans assigning
-# rows/lists/segments to mesh shards, and replica-group query fan-out.
+# Distribution utilities: mesh-sharding rules for every model family,
+# placement plans assigning rows/lists/segments to mesh shards, and
+# replica-group query fan-out.
 from repro.dist import placement
 from repro.dist.placement import Placement
 from repro.dist.replica import ReplicaSet, replicated_query_plan, submeshes

@@ -26,17 +26,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental location, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, **kwargs):  # type: ignore[no-redef]
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_04(f, **kwargs)
 
 __all__ = [
     "P",
@@ -46,6 +37,7 @@ __all__ = [
     "dp_axes",
     "corpus_shards",
     "sentinel_gids",
+    "shard_rows",
     "lm_params_sharding",
     "lm_opt_sharding",
     "lm_grad_specs",
@@ -86,6 +78,15 @@ def corpus_shards(mesh: Mesh) -> tuple[tuple[str, ...], int]:
     """
     axes = tuple(mesh.axis_names)
     return axes, int(mesh.devices.size)
+
+
+def shard_rows(mesh: Mesh, x: jax.Array) -> jax.Array:
+    """Place ``x`` with its leading axis split over every mesh axis (the
+    ``corpus_shards`` rule).  Plans place their corpus once, at plan
+    time, so no call reshards it from the device the build left it on."""
+    axes, _ = corpus_shards(mesh)
+    return jax.device_put(
+        x, NamedSharding(mesh, P(axes, *([None] * (x.ndim - 1)))))
 
 
 def sentinel_gids(gids, valid, *, shard, local_rows, n_total: int,

@@ -10,6 +10,7 @@
 # stream layer uses to map internal rows back to external ids.
 from repro.engine.scorer import (
     build_pq_lut,
+    by_query_block,
     chunked_topk,
     distributed_topk,
     get_lut_cache,
@@ -34,6 +35,7 @@ __all__ = [
     "PQStore",
     "PQ_CODE_BITS",
     "build_pq_lut",
+    "by_query_block",
     "quantize_pq_lut",
     "topk",
     "topk_among",

@@ -373,6 +373,29 @@ def topk(
 # candidate-set top-k (IVF fine scoring and friends)
 # --------------------------------------------------------------------------
 
+#: bound on one block of gathered candidate rows, [queries, L, d] at four
+#: bytes an element: candidate scans run over query blocks that fit it, so
+#: a large bucket against long IVF lists stays within device memory
+GATHER_BYTES = 1 << 30
+
+
+def by_query_block(fn, q: jax.Array, cand: jax.Array):
+    """``fn(q, cand)`` over blocks of as many queries as fit GATHER_BYTES,
+    so one block's gathered rows live at a time.
+
+    ``fn`` must treat queries independently.  Pad queries are zeros with
+    empty (-1) candidates and are cut from the result."""
+    n_q, width = cand.shape
+    block = max(1, min(n_q, GATHER_BYTES // max(1, 4 * width * q.shape[-1])))
+    n_blocks = -(-n_q // block)
+    pad = n_blocks * block - n_q
+    qb = jnp.pad(q, ((0, pad), (0, 0))).reshape(n_blocks, block, -1)
+    cb = jnp.pad(cand, ((0, pad), (0, 0)), constant_values=-1).reshape(
+        n_blocks, block, width)
+    out = jax.lax.map(lambda a: fn(*a), (qb, cb))
+    return jax.tree.map(lambda o: o.reshape((-1,) + o.shape[2:])[:n_q], out)
+
+
 @partial(jax.jit, static_argnames=("k", "metric"))
 def topk_among(
     q_codes: jax.Array,
@@ -394,22 +417,26 @@ def topk_among(
     [Q, L, d] block) rather than a vmapped per-query dot: the batched
     form lowers identically inside ``shard_map``, which is what lets a
     sharded IVF plan reproduce this function's scores bit-exactly
-    (DESIGN.md §15).
+    (DESIGN.md §15).  Queries run in blocks (``by_query_block``), so
+    the gathered rows stay within GATHER_BYTES.
     """
     L = cand_ids.shape[1]
     k_eff = min(k, L)
 
-    ok = cand_ids >= 0
-    safe = jnp.where(ok, cand_ids, 0)
-    if mask is not None:
-        ok = ok & mask.astype(bool)[safe]
-    rows = store.take(safe)                              # [Q, L, d]
-    s = D.scores_among(q_codes, rows, metric, quantized=store.quantized)
-    s = jnp.where(ok, s.astype(jnp.float32), NEG)
-    s, pos = jax.lax.top_k(s, k_eff)
-    i = jnp.where(
-        s > NEG, jnp.take_along_axis(cand_ids, pos, axis=1), -1
-    ).astype(jnp.int32)
+    def block(q, cand):
+        ok = cand >= 0
+        safe = jnp.where(ok, cand, 0)
+        if mask is not None:
+            ok = ok & mask.astype(bool)[safe]
+        rows = store.take(safe)                          # [q, L, d]
+        s = D.scores_among(q, rows, metric, quantized=store.quantized)
+        s = jnp.where(ok, s.astype(jnp.float32), NEG)
+        s, pos = jax.lax.top_k(s, k_eff)
+        return s, jnp.where(
+            s > NEG, jnp.take_along_axis(cand, pos, axis=1), -1
+        ).astype(jnp.int32)
+
+    s, i = by_query_block(block, q_codes, cand_ids)
     if k_eff < k:
         s = jnp.pad(s, ((0, 0), (0, k - k_eff)), constant_values=NEG)
         i = jnp.pad(i, ((0, 0), (0, k - k_eff)), constant_values=-1)
@@ -502,24 +529,27 @@ def topk_among_regional(
     (``assign [N]``) selects its own ``region_scale`` / ``region_zero``
     rows ([R, d]) and the code is mapped back to fp32 before the metric.
     Everything else (empty-slot masking, -1 pads, base rebasing, the
-    optional row-space ``mask``) matches ``topk_among``.
+    optional row-space ``mask``, query blocks) matches ``topk_among``.
     """
     L = cand_ids.shape[1]
     k_eff = min(k, L)
 
-    ok = cand_ids >= 0
-    safe = jnp.where(ok, cand_ids, 0)
-    if mask is not None:
-        ok = ok & mask.astype(bool)[safe]
-    codes = store.take(safe).astype(jnp.float32)         # [Q, L, d]
-    reg = assign[safe]                                   # [Q, L]
-    x = codes * region_scale[reg] + region_zero[reg]
-    s = D.scores_among(queries, x, metric, quantized=False)
-    s = jnp.where(ok, s.astype(jnp.float32), NEG)
-    s, pos = jax.lax.top_k(s, k_eff)
-    i = jnp.where(
-        s > NEG, jnp.take_along_axis(cand_ids, pos, axis=1), -1
-    ).astype(jnp.int32)
+    def block(q, cand):
+        ok = cand >= 0
+        safe = jnp.where(ok, cand, 0)
+        if mask is not None:
+            ok = ok & mask.astype(bool)[safe]
+        codes = store.take(safe).astype(jnp.float32)     # [q, L, d]
+        reg = assign[safe]                               # [q, L]
+        x = codes * region_scale[reg] + region_zero[reg]
+        s = D.scores_among(q, x, metric, quantized=False)
+        s = jnp.where(ok, s.astype(jnp.float32), NEG)
+        s, pos = jax.lax.top_k(s, k_eff)
+        return s, jnp.where(
+            s > NEG, jnp.take_along_axis(cand, pos, axis=1), -1
+        ).astype(jnp.int32)
+
+    s, i = by_query_block(block, queries, cand_ids)
     if k_eff < k:
         s = jnp.pad(s, ((0, 0), (0, k - k_eff)), constant_values=NEG)
         i = jnp.pad(i, ((0, 0), (0, k - k_eff)), constant_values=-1)
@@ -607,13 +637,19 @@ def distributed_topk(
 
 def build_pq_lut(queries: jax.Array, store: PQStore, metric: str) -> jax.Array:
     """Per-query ADC lookup table [Q, M, K] f32 of query-to-codeword
-    scores (K = ``store.n_codewords``)."""
+    scores (K = ``store.n_codewords``).
+
+    Both metrics are an elementwise product and a reduction over the
+    subspace width, never a dot: a dot's accumulation order depends on
+    the batch shape, so a query padded into a Searcher bucket would get
+    a LUT an ulp away from its eager one.
+    """
     q = jnp.asarray(queries, jnp.float32)
     Q, d = q.shape
     ds = d // store.m
     qs = q.reshape(Q, store.m, ds)
     if metric == "ip":
-        return jnp.einsum("qmd,mkd->qmk", qs, store.codebooks)
+        return jnp.sum(qs[:, :, None, :] * store.codebooks[None], -1)
     diff = qs[:, :, None, :] - store.codebooks[None]    # l2 (negated)
     return -jnp.sum(diff * diff, -1)
 
@@ -721,8 +757,22 @@ def _topk_pq(
             key, lambda: jax.block_until_ready(
                 _prepare_pq_lut(queries, store, metric))
         )
-    else:
-        lut = _prepare_pq_lut(queries, store, metric)
+        return _topk_pq_from_lut(lut, store, k, metric, chunk,
+                                 use_pallas=use_pallas, interpret=interpret,
+                                 cfg=cfg, mask=mask)
+    return _topk_pq_built(queries, store, k, metric, chunk,
+                          use_pallas=use_pallas, interpret=interpret,
+                          cfg=cfg, mask=mask)
+
+
+@partial(jax.jit, static_argnames=("k", "metric", "chunk", "use_pallas",
+                                   "interpret", "cfg"))
+def _topk_pq_built(queries, store, k, metric, chunk, use_pallas=True,
+                   interpret=None, cfg=None, mask=None):
+    """Table build and scan as one program: the eager call and a planned
+    bucket (which inlines it) then compile the same fusion, so fp32
+    tables score bit-identically on both."""
+    lut = _prepare_pq_lut(queries, store, metric)
     return _topk_pq_from_lut(lut, store, k, metric, chunk,
                              use_pallas=use_pallas, interpret=interpret,
                              cfg=cfg, mask=mask)

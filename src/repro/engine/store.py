@@ -166,7 +166,11 @@ class CodeStore:
         if not self.quantized:
             return jnp.asarray(queries, jnp.float32)
         p = self.params
-        q = K.quantize(queries, p.lo, p.hi, p.zero, bits=p.bits)
+        # the same Eq. 1 map as the corpus kernel, in XLA: a query batch
+        # is too small to stream, and sharded plans encode replicated
+        # queries, which XLA cannot partition around a Mosaic call
+        q = K.quantize(queries, p.lo, p.hi, p.zero, bits=p.bits,
+                       use_pallas=False)
         if self.packed and self.d_eff != self.d:
             q = jnp.pad(q, ((0, 0), (0, self.d_eff - self.d)))
         return q

@@ -199,10 +199,13 @@ def overfetch(k: int, selectivity: float, n: int) -> int:
     a ``selectivity`` fraction of candidates pass, fetch ``k/selectivity``
     plus a safety margin, clamped to the corpus.  Selectivity 0 (filter-
     all) clamps to n: the oracle answer is "all pad", reached by scanning
-    everything and finding no survivor.
+    everything and finding no survivor.  The depth never exceeds n, not
+    even when k does.
     """
+    if not n:
+        return k
     if selectivity >= 1.0:
-        return min(k, n) if n else k
+        return min(k, n)
     sel = max(float(selectivity), 1e-9)
     want = int(np.ceil(k / sel)) + 8
-    return max(k, min(want, n))
+    return min(max(k, want), n)

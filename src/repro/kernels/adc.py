@@ -75,6 +75,7 @@ def make_adc4_tile(n_codewords: int):
 
     def tile(lut_even: jax.Array, lut_odd: jax.Array,
              packed: jax.Array) -> jax.Array:
+        packed = packed.astype(jnp.int32)     # Mosaic: no 8-bit vector shift
         lo = packed & 0x0F
         hi = (packed >> 4) & 0x0F
         return (_dot_i32(lut_even, _onehot_codes(lo, n_codewords))

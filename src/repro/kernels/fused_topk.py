@@ -11,9 +11,9 @@ corpus axis, the standard Pallas accumulation pattern).  The [Q, N]
 score matrix never exists in HBM.
 
 Per tile the merge is a k-step select-and-mask sweep over the
-concatenated [bq, k + bn] candidates: max + argmax + one-hot mask, all
-dense VPU ops (no sorts, no dynamic stores), O(k (k + bn)) per tile
-against the tile's O(bn d) MXU score work.  Padding rows are id-masked
+concatenated [bq, k + bn] candidates: max, smallest id among the maxima
+and a mask, all dense VPU ops (no sorts, no dynamic stores), O(k (k + bn))
+per tile against the tile's O(bn d) MXU score work.  Padding rows are id-masked
 *inside* the kernel (score -> -inf, id -> -1), so zero-padding can never
 win under L2 — callers get only valid ids back, no sentinel hazard.
 
@@ -80,25 +80,28 @@ _TILE_FNS = {("ip", False): _ip_tile, ("l2", False): _l2_tile,
 def _merge_tile(best_s, best_i, s, ids, k: int):
     """Merge a [bq, bn] score tile into the running [bq, k] best set.
 
-    k-step select-and-mask: each step extracts the row max of the
-    concatenated candidates and one-hot-masks it out — everything stays a
-    dense 2-D op (argmax ties resolve to the first position, so the
-    result is deterministic and sorted best-first).
+    k-step select-and-mask: each step takes the row max of the
+    concatenated candidates and, among the entries holding it, the
+    smallest id, then masks that entry out — everything stays a dense
+    2-D op.  Ties are ordered by id, not by lane position, so the result
+    (sorted best-first, lower id first among equal scores) is the same
+    as ``lax.top_k`` over the id-ordered corpus whatever order the
+    compiler's reductions visit the lanes in.  Once only masked entries
+    remain, the step emits the (NEG, -1) pad.
     """
     cs = jnp.concatenate([best_s, s], axis=1)              # [bq, k + bn]
     ci = jnp.concatenate([best_i, ids], axis=1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, cs.shape, 1)
     kcols = jax.lax.broadcasted_iota(jnp.int32, best_s.shape, 1)
+    no_id = jnp.iinfo(jnp.int32).max
 
     def step(j, carry):
         cs, out_s, out_i = carry
         m = jnp.max(cs, axis=1, keepdims=True)             # [bq, 1]
-        p = jnp.argmax(cs, axis=1)[:, None]                # [bq, 1]
-        onehot = cols == p
-        sel = jnp.sum(jnp.where(onehot, ci, 0), axis=1, keepdims=True)
+        at_m = cs == m
+        sel = jnp.min(jnp.where(at_m, ci, no_id), axis=1, keepdims=True)
         out_s = jnp.where(kcols == j, m, out_s)
-        out_i = jnp.where(kcols == j, sel, out_i)
-        return jnp.where(onehot, NEG, cs), out_s, out_i
+        out_i = jnp.where(kcols == j, jnp.where(m > NEG, sel, -1), out_i)
+        return jnp.where(at_m & (ci == sel), NEG, cs), out_s, out_i
 
     _, out_s, out_i = jax.lax.fori_loop(
         0, k, step,
@@ -124,10 +127,11 @@ def _make_kernel(score_tile, k: int, bn: int, n_valid: int,
         gid = j * bn + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         ok = gid < n_valid
         if with_mask:
-            # predicate bitmap rides the corpus grid axis as an [bn, 1]
-            # int8 column — the filter ANDs into the same pad fence, so
-            # a filtered row dies exactly like a pad row (DESIGN.md §16)
-            ok = ok & (m_ref[...][:, 0] != 0)[None, :]
+            # predicate bitmap rides the corpus grid axis as a [1, bn]
+            # int8 row, lane-major like the score tile's columns — the
+            # filter ANDs into the same pad fence, so a filtered row dies
+            # exactly like a pad row (DESIGN.md §16)
+            ok = ok & (m_ref[...] != 0)
         s = jnp.where(ok, s, NEG)
         ids = jnp.where(ok, gid, -1)
         bs, bi = _merge_tile(os_ref[...], oi_ref[...], s, ids, k)
@@ -150,8 +154,8 @@ def _fused_call(score_tile, inputs, corpus, *, k, n_valid, bq, bn, interpret,
     in_specs = q_specs + [x_spec]
     if mask is not None:
         assert mask.shape[0] == N, (mask.shape, N)
-        operands.append(mask.reshape(N, 1).astype(jnp.int8))
-        in_specs.append(pl.BlockSpec((bn, 1), lambda i, j: (j, 0)))
+        operands.append(mask.reshape(1, N).astype(jnp.int8))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
     out_spec = pl.BlockSpec((bq, k), lambda i, j: (i, 0))
     return pl.pallas_call(
         _make_kernel(score_tile, k, bn, n_valid, with_mask=mask is not None),

@@ -30,9 +30,14 @@ BN = 512   # corpus rows per tile
 
 
 def unpack_nibbles(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """uint8 tile -> (lo, hi) int8 nibble planes in [-8, 7] (VPU shift-mask)."""
-    lo = (x & 0x0F).astype(jnp.int8) - 8
-    hi = ((x >> 4) & 0x0F).astype(jnp.int8) - 8
+    """uint8 tile -> (lo, hi) int8 nibble planes in [-8, 7] (VPU shift-mask).
+
+    The shift-mask runs on int32 lanes: Mosaic has no 8-bit vector shift
+    or subtract.
+    """
+    x = x.astype(jnp.int32)
+    lo = ((x & 0x0F) - 8).astype(jnp.int8)
+    hi = (((x >> 4) & 0x0F) - 8).astype(jnp.int8)
     return lo, hi
 
 

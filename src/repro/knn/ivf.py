@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro import engine
 from repro.core import distances as D
+from repro.core.blocks import fill_row_blocks
 from repro.core import quant as Qz
 from repro.knn import base as B
 from repro.knn import registry
@@ -42,6 +43,23 @@ from repro.knn.spec import (
 # k-means (Lloyd) — the coarse quantizer
 # --------------------------------------------------------------------------
 
+#: rows per k-means assignment block: bounds the [rows, C] score
+#: temporaries, so a multi-million-row build fits one chip
+ASSIGN_BLOCK = 65536
+
+
+@jax.jit
+def nearest_centroid(x: jax.Array, cents: jax.Array) -> jax.Array:
+    """[N] int32 id of each row's L2-nearest centroid, in row blocks."""
+    block = min(ASSIGN_BLOCK, x.shape[0])
+
+    def assign(_b, start):
+        xb = jax.lax.dynamic_slice_in_dim(x, start, block)
+        return jnp.argmax(D.l2_scores(xb, cents), axis=-1).astype(jnp.int32)
+
+    return fill_row_blocks(jnp.zeros((x.shape[0],), jnp.int32), block, assign)
+
+
 @partial(jax.jit, static_argnames=("n_clusters", "iters"))
 def kmeans(
     x: jax.Array, n_clusters: int, key: jax.Array, iters: int = 10
@@ -53,12 +71,9 @@ def kmeans(
     cents = x[init_ids]
 
     def step(cents, _):
-        # assign by L2 (larger-is-closer negated L2 scores)
-        s = D.l2_scores(x, cents)                     # [N, C]
-        assign = jnp.argmax(s, axis=-1)               # [N]
-        one_hot = jax.nn.one_hot(assign, n_clusters, dtype=jnp.float32)
-        counts = one_hot.sum(0)                       # [C]
-        sums = one_hot.T @ x                          # [C, d]
+        assign = nearest_centroid(x, cents)
+        counts = jnp.bincount(assign, length=n_clusters).astype(jnp.float32)
+        sums = jax.ops.segment_sum(x, assign, num_segments=n_clusters)
         new = sums / jnp.maximum(counts[:, None], 1.0)
         # keep old centroid for empty clusters
         new = jnp.where(counts[:, None] > 0, new, cents)
@@ -129,7 +144,7 @@ class IVFIndex:
             key = jax.random.PRNGKey(0)
         corpus = jnp.asarray(corpus, jnp.float32)
         cents = kmeans(corpus, nlist, key, iters=kmeans_iters)
-        assign = jnp.argmax(D.l2_scores(corpus, cents), axis=-1)
+        assign = nearest_centroid(corpus, cents)
 
         # bucket ids into fixed-width lists (host-side; build is offline)
         import numpy as np
@@ -306,8 +321,8 @@ class IVFIndex:
         import numpy as np
 
         from repro.dist.placement import Placement
-        from repro.dist.sharding import P, corpus_shards, shard_map
-        from repro.engine import distributed_topk
+        from repro.dist.sharding import P, corpus_shards, shard_map, shard_rows
+        from repro.engine import by_query_block, distributed_topk
         from repro.engine.scorer import NEG
         from repro.core import pack as PK
 
@@ -351,10 +366,10 @@ class IVFIndex:
                          data_np.dtype)
         for s, gids in enumerate(shard_gids):
             codes[s, : gids.size] = data_np[gids]
-        codes = jnp.asarray(codes)
+        codes = shard_rows(mesh, jnp.asarray(codes))
         owner = jnp.asarray(owner)
         local_of = jnp.asarray(local_of)
-        shard_idx = jnp.arange(n_shards, dtype=jnp.int32)
+        shard_idx = shard_rows(mesh, jnp.arange(n_shards, dtype=jnp.int32))
 
         W = nprobe * self.max_list
         k_eff = min(k, W)
@@ -364,26 +379,30 @@ class IVFIndex:
         def local(q, cand, codes_s, idx):
             codes_s = codes_s[0]                    # [rows_max, width]
             shard = idx[0]
-            safe = jnp.clip(cand, 0, n - 1)
-            ok = (cand >= 0) & (owner[safe] == shard)
-            if fmask is not None:
-                ok = ok & fmask[safe]
-            rows = codes_s[jnp.where(ok, local_of[safe], 0)]   # [Q, W, w]
-            if store.packed:
-                rows = PK.unpack_int4(rows)
-            if regional:
-                reg = self.regions.assign[safe]                # [Q, W]
-                x = (rows.astype(jnp.float32) * self.regions.scale[reg]
-                     + self.regions.zero[reg])
-                s = D.scores_among(q, x, self.metric, quantized=False)
-            else:
-                s = D.scores_among(q, rows, self.metric,
-                                   quantized=store.quantized)
-            s = jnp.where(ok, s.astype(jnp.float32), NEG)
-            ls, pos = jax.lax.top_k(s, k_eff)
-            # merge on candidate POSITIONS — the id space whose ascending
-            # tie-break equals topk_among's stable top_k
-            li = jnp.where(ls > NEG, pos, -1).astype(jnp.int32)
+
+            def block(q, cand):     # the same query blocks as topk_among
+                safe = jnp.clip(cand, 0, n - 1)
+                ok = (cand >= 0) & (owner[safe] == shard)
+                if fmask is not None:
+                    ok = ok & fmask[safe]
+                rows = codes_s[jnp.where(ok, local_of[safe], 0)]  # [q, W, w]
+                if store.packed:
+                    rows = PK.unpack_int4(rows)
+                if regional:
+                    reg = self.regions.assign[safe]               # [q, W]
+                    x = (rows.astype(jnp.float32) * self.regions.scale[reg]
+                         + self.regions.zero[reg])
+                    s = D.scores_among(q, x, self.metric, quantized=False)
+                else:
+                    s = D.scores_among(q, rows, self.metric,
+                                       quantized=store.quantized)
+                s = jnp.where(ok, s.astype(jnp.float32), NEG)
+                ls, pos = jax.lax.top_k(s, k_eff)
+                # merge on candidate POSITIONS — the id space whose
+                # ascending tie-break equals topk_among's stable top_k
+                return ls, jnp.where(ls > NEG, pos, -1).astype(jnp.int32)
+
+            ls, li = by_query_block(block, q, cand)
             return distributed_topk(ls, li, k_eff, axes, 0, tie_break="id")
 
         inner = shard_map(
@@ -481,7 +500,7 @@ class IVFIndex:
                 "index with an '...,regions' factory (e.g. 'ivf64,lpq8,regions')"
             )
         live = jnp.asarray(live_corpus, jnp.float32)
-        live_assign = jnp.argmax(D.l2_scores(live, self.centroids), axis=-1)
+        live_assign = nearest_centroid(live, self.centroids)
         return self.regions.drift_report(live, live_assign)
 
     # ------------------------------------------------------------------

@@ -196,7 +196,7 @@ class PQIndex:
         from repro.core import pack as PK
         from repro.dist.placement import Placement
         from repro.dist.sharding import (
-            P, corpus_shards, sentinel_gids, shard_map,
+            P, corpus_shards, sentinel_gids, shard_map, shard_rows,
         )
         from repro.engine import distributed_topk, merge_topk
         from repro.engine.scorer import NEG, _prepare_pq_lut
@@ -220,9 +220,9 @@ class PQIndex:
         tile_rows = min(sp.chunk, rows_per)
         n_tiles = -(-rows_per // tile_rows)
         padded_rows = n_tiles * tile_rows
-        data = (jnp.pad(store.codes, ((0, pad), (0, 0))) if pad
-                else store.codes)
-        shard_idx = jnp.arange(n_shards, dtype=jnp.int32)
+        data = shard_rows(mesh, jnp.pad(store.codes, ((0, pad), (0, 0)))
+                          if pad else store.codes)
+        shard_idx = shard_rows(mesh, jnp.arange(n_shards, dtype=jnp.int32))
 
         def tile_scores(lt, tile_codes):     # same math as _topk_pq_from_lut
             rows = (PK.unpack_uint4(tile_codes)[:, : store.m]
@@ -239,7 +239,7 @@ class PQIndex:
         fmask = None
         if sp.filter is not None:
             fm = jnp.asarray(sp.filter.aligned(n)).astype(jnp.int8)
-            fmask = jnp.pad(fm, (0, pad)) if pad else fm
+            fmask = shard_rows(mesh, jnp.pad(fm, (0, pad)) if pad else fm)
 
         def local(lt, shard, mshard, idx):
             gid0 = idx[0] * rows_per

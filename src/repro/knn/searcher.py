@@ -192,7 +192,9 @@ def sharded_scan_plan(
     from repro.core import distances as D
     from repro.core import pack as PK
     from repro.dist.placement import Placement
-    from repro.dist.sharding import P, corpus_shards, sentinel_gids, shard_map
+    from repro.dist.sharding import (
+        P, corpus_shards, sentinel_gids, shard_map, shard_rows,
+    )
     from repro.engine import distributed_topk
 
     if store.base:
@@ -219,12 +221,13 @@ def sharded_scan_plan(
     tile_rows = min(chunk, rows_per)
     n_tiles = -(-rows_per // tile_rows)
     padded_rows = n_tiles * tile_rows          # per-shard sentinel band width
-    data = jnp.pad(store.data, ((0, pad), (0, 0))) if pad else store.data
-    shard_idx = jnp.arange(n_shards, dtype=jnp.int32)
+    data = shard_rows(
+        mesh, jnp.pad(store.data, ((0, pad), (0, 0))) if pad else store.data)
+    shard_idx = shard_rows(mesh, jnp.arange(n_shards, dtype=jnp.int32))
     fmask = None
     if mask is not None:
         fm = jnp.asarray(mask).astype(jnp.int8)
-        fmask = jnp.pad(fm, (0, pad)) if pad else fm
+        fmask = shard_rows(mesh, jnp.pad(fm, (0, pad)) if pad else fm)
 
     def local(q, shard, mshard, idx):
         gid0 = idx[0] * rows_per
@@ -560,7 +563,7 @@ class Searcher:
             return B.SearchResult(s, i, stats)
 
         self._run = run
-        self._jitted = jax.jit(run) if batch_sizes is not None else run
+        self._executables: dict = {}     # (shape, dtype) -> (jit fn, arrays)
 
     # -- accounting --------------------------------------------------------
     @property
@@ -584,6 +587,37 @@ class Searcher:
             out.append(next(b for b in self.batch_sizes if b >= rows))
             q_len -= rows
         return tuple(out)
+
+    def lower(self, q_len: int):
+        """Lower the executable of the bucket a ``q_len``-query request
+        runs in (``.compile().as_text()`` shows which kernels it holds)."""
+        q = jax.ShapeDtypeStruct((self.buckets_for(q_len)[0], self._qdim),
+                                 jnp.float32)
+        fn, arrays = self._executable(q)
+        return fn.lower(arrays, q)
+
+    def _executable(self, q) -> tuple[Callable, list]:
+        """The bucket executable for ``q``'s shape, and the arrays it runs on.
+
+        The plan closes over the index (codes, lists, graphs, placed
+        shards).  jit would bake closed-over arrays into the program as
+        constants: gigabytes of HLO per bucket at a deployment's size,
+        minutes of compile and host memory beyond a chip host's.  So the
+        runner is traced once to a jaxpr and every array it closed over
+        is passed to the compiled program as an argument instead, in the
+        placement the plan gave it.
+        """
+        key = (tuple(q.shape), jnp.dtype(q.dtype))
+        if key not in self._executables:
+            closed, out = jax.make_jaxpr(self._run, return_shape=True)(
+                jax.ShapeDtypeStruct(q.shape, q.dtype))
+            tree = jax.tree.structure(out)
+            fn = jax.jit(lambda arrays, x: jax.tree.unflatten(
+                tree, jax.core.eval_jaxpr(closed.jaxpr, arrays, x)))
+            arrays = [c if isinstance(c, jax.Array) else jnp.asarray(c)
+                      for c in closed.consts]
+            self._executables[key] = (fn, arrays)
+        return self._executables[key]
 
     # -- execution ---------------------------------------------------------
     def _validate_queries(self, queries) -> jax.Array:
@@ -627,7 +661,8 @@ class Searcher:
             bucket = next(b for b in self.batch_sizes if b >= rows)
             if bucket > rows:
                 sl = jnp.pad(sl, ((0, bucket - rows), (0, 0)))
-            res = self._jitted(sl)
+            fn, arrays = self._executable(sl)
+            res = fn(arrays, sl)
             parts_s.append(res.scores[:rows])
             parts_i.append(res.ids[:rows])
             padded_q += bucket - rows

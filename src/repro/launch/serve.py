@@ -70,10 +70,11 @@ _AGG_KEYS = ("candidates", "bytes_read", "chunks", "padded_q", "reranked",
 
 def _request_sizes(n_requests: int, batch: int, mixed: bool) -> list[int]:
     """Per-request query counts: fixed ``batch``, or a mixed cycle that
-    exercises several buckets (the realistic open-loop traffic shape)."""
+    exercises several buckets (the realistic open-loop traffic shape; at
+    ``batch`` 256 it is exactly the default buckets 1/8/32/256)."""
     if not mixed:
         return [batch] * n_requests
-    cycle = [1, max(1, batch // 4), batch]
+    cycle = [1, max(1, batch // 32), max(1, batch // 8), batch]
     return [cycle[i % len(cycle)] for i in range(n_requests)]
 
 
@@ -186,7 +187,23 @@ def _index_dim(index) -> int | None:
     return int(store.m * store.codebooks.shape[-1])
 
 
-def main(argv: list[str] | None = None) -> None:
+def _placeable(index) -> bool:
+    """Can ``index`` move to another device as one pytree?"""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(index)
+    return not (len(leaves) == 1 and leaves[0] is index)
+
+
+def main(argv: list[str] | None = None) -> dict:
+    """Build, warm and serve one session; print the report.
+
+    Returns what the session served, for callers that check answers:
+    ``index``, ``searcher`` (the first replica's primary plan),
+    ``replica_searchers``, ``corpus``, ``queries``, ``build_s`` and
+    ``warm_s`` (bucket compilation and first calls).  Exits non-zero
+    when a background maintenance round failed.
+    """
     args = _parse_args(argv)
 
     # profile first: platform/XLA/core-pinning are process-start state
@@ -246,11 +263,10 @@ def main(argv: list[str] | None = None) -> None:
     sizes = _request_sizes(args.requests, args.batch, args.mixed)
     n_extra = 8 if args.mutate else 0
     corpus, queries, _metric = synthetic.load(
-        "product", args.n + n_extra, sum(sizes)
+        "product", args.n + n_extra, sum(sizes), d=args.d
     )
-    corpus = corpus[:, : args.d]
-    queries = queries[:, : args.d]
-    corpus, extra_rows = corpus[: args.n], corpus[args.n:]
+    if n_extra:
+        corpus, extra_rows = corpus[: args.n], corpus[args.n:]
 
     if index is None:
         t0 = time.perf_counter()
@@ -303,6 +319,7 @@ def main(argv: list[str] | None = None) -> None:
 
     mesh = None
     replica_meshes = None
+    replica_devices = None
     n_replicas = max(1, args.replicas)
     if n_replicas > 1:
         if args.shards > 1 and len(jax.devices()) > 1:
@@ -323,8 +340,15 @@ def main(argv: list[str] | None = None) -> None:
                       "replica serves unsharded")
                 replica_meshes = [None] * n_replicas
         else:
-            # CPU-thread replicas sharing the device pool
             replica_meshes = [None] * n_replicas
+            devs = jax.devices()
+            if len(devs) >= n_replicas and _placeable(index):
+                # one device per replica: its index copy, and so its
+                # compiled plan, live there
+                replica_devices = devs[:n_replicas]
+            else:
+                print(f"[serve] {n_replicas} replicas share the default "
+                      f"device ({len(devs)} device(s), kind {index.kind!r})")
     elif args.shards > 1:
         n_dev = len(jax.devices())
         if args.shards > n_dev:
@@ -358,8 +382,9 @@ def main(argv: list[str] | None = None) -> None:
             counters=telemetry.counters,
         )
 
-    def make_searchers(shard_mesh=mesh):
-        primary = index.searcher(
+    def make_searchers(shard_mesh=mesh, idx=None):
+        idx = index if idx is None else idx
+        primary = idx.searcher(
             args.k, sp, batch_sizes=buckets, shards=shard_mesh,
             rerank=args.rerank_depth or None,
         )
@@ -369,7 +394,7 @@ def main(argv: list[str] | None = None) -> None:
                 primary.rerank.depth if primary.rerank else 0, args.k
             )
             # params(sp, k) also shrinks cascade stage budgets (floor k)
-            degraded = index.searcher(
+            degraded = idx.searcher(
                 args.k, ctrl.policy.params(sp, args.k), batch_sizes=buckets,
                 shards=shard_mesh, rerank=(d_depth or False),
             )
@@ -396,7 +421,9 @@ def main(argv: list[str] | None = None) -> None:
         replica_primaries: dict = {}
 
         def make_replica(r):
-            primary, degraded = make_searchers(replica_meshes[r])
+            idx = (None if replica_devices is None
+                   else jax.device_put(index, replica_devices[r]))
+            primary, degraded = make_searchers(replica_meshes[r], idx)
             replica_primaries[r] = primary
             # the result cache is per replica (TTLLRUCache is not
             # thread-safe; replica workers are threads)
@@ -418,8 +445,10 @@ def main(argv: list[str] | None = None) -> None:
 
             return run
 
+        t_warm = time.perf_counter()
         replicas = ReplicaSet(make_replica, n_replicas,
                               max_queue=args.max_queue, telemetry=telemetry)
+        warm_s = time.perf_counter() - t_warm
         head = replica_primaries[0]
     else:
         searcher, searcher_deg = make_searchers()
@@ -495,7 +524,11 @@ def main(argv: list[str] | None = None) -> None:
                 jax.block_until_ready(degraded(queries[:sz]).ids)
 
     if replicas is None:
+        t_warm = time.perf_counter()
         warm(searcher, searcher_deg)   # replicas warm inside make_replica
+        warm_s = time.perf_counter() - t_warm
+    print(f"[serve] warmup: {warm_s:.2f}s (bucket compiles + first calls "
+          f"for request sizes {sorted(set(sizes))})")
 
     maint = None
     if args.maintenance:
@@ -724,6 +757,17 @@ def main(argv: list[str] | None = None) -> None:
         telemetry.to_json(args.telemetry_out)
         print(f"[serve] telemetry -> {args.telemetry_out} "
               f"({len(telemetry.events)} events)")
+    if maint is not None and telemetry.counters["maintenance_errors"]:
+        raise SystemExit(
+            f"[serve] {telemetry.counters['maintenance_errors']} background "
+            "maintenance round(s) failed (maintenance_error telemetry events)")
+    return {
+        "index": index, "searcher": head,
+        "replica_searchers": ([replica_primaries[r] for r in range(n_replicas)]
+                              if replicas is not None else [head]),
+        "corpus": corpus, "queries": queries,
+        "build_s": build_s, "warm_s": warm_s,
+    }
 
 
 if __name__ == "__main__":

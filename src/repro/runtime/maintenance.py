@@ -52,6 +52,7 @@ shared telemetry counters and logged as ``maintenance/*`` spans.
 from __future__ import annotations
 
 import threading
+import traceback
 from typing import Optional
 
 
@@ -193,9 +194,11 @@ class MaintenanceScheduler:
             try:
                 self.run_once()
             except Exception as e:  # noqa: BLE001 — never kill the server
+                # counted and logged here; serve exits non-zero on any
                 self.counters["maintenance_errors"] += 1
                 if self.telemetry is not None:
-                    self.telemetry.event("maintenance_error", error=repr(e))
+                    self.telemetry.event("maintenance_error", error=repr(e),
+                                         traceback=traceback.format_exc())
 
     def stop(self, timeout: float = 10.0) -> None:
         if self._thread is None:

@@ -31,6 +31,13 @@ from typing import Optional
 
 ENV_VAR = "REPRO_RUNTIME_PROFILE"
 
+#: where ``apply`` keeps JAX's persistent compilation cache when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: one fixed path inside the
+#: checkout, since the path is part of every cache key
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
 _PROFILE_FIELDS = ("name", "platform", "host_device_count", "xla_flags",
                    "nan_debug", "x64", "seed", "deterministic")
 
@@ -40,8 +47,9 @@ class RuntimeProfile:
     """One named runtime environment, resolved at process start.
 
     name               registry key, stamped into every artifact
-    platform           forced jax platform ("cpu"/"gpu"/"tpu"); None =
-                       let jax pick (the honest-autodetect default)
+    platform           required jax platform ("cpu"/"gpu"/"tpu"): apply
+                       and stamp raise unless the first device is on
+                       it; None = let jax pick (the autodetect default)
     host_device_count  pin this many host CPU devices
                        (``--xla_force_host_platform_device_count`` — the
                        sharded-serving / core-pinning knob); None = leave
@@ -100,12 +108,10 @@ PROFILES: dict[str, RuntimeProfile] = {
     # NaN after division by zero-σ dims)
     "debug-nan": RuntimeProfile(name="debug-nan", platform="cpu",
                                 nan_debug=True),
-    # TPU serving: leave the platform to autodetect-with-tpu-preference
-    # and enable the latency-hiding scheduler class of flags
-    "tpu-serve": RuntimeProfile(
-        name="tpu-serve", platform="tpu",
-        xla_flags=("--xla_tpu_enable_latency_hiding_scheduler=true",),
-    ),
+    # TPU serving: the first device must be a TPU, or apply() raises —
+    # never a silent CPU fallback.  No TPU flags here: libtpu refuses
+    # TPU-only flags passed through XLA_FLAGS and aborts the process.
+    "tpu-serve": RuntimeProfile(name="tpu-serve", platform="tpu"),
 }
 
 #: the profile ``apply`` actually installed in this process (at most one)
@@ -172,6 +178,11 @@ def apply(profile: RuntimeProfile) -> RuntimeProfile:
     device count and XLA flags only take effect at backend init.  A
     second ``apply`` of the *same* profile is a no-op; a different one
     warns and is ignored (the backend is already up — restart to switch).
+
+    A profile that names a platform raises ``RuntimeError`` unless the
+    first device is on it.  The persistent compilation cache is left to
+    ``JAX_COMPILATION_CACHE_DIR`` when that is set, and otherwise kept at
+    :data:`COMPILE_CACHE_DIR` inside the checkout.
     """
     global _ACTIVE
     import jax
@@ -195,11 +206,38 @@ def apply(profile: RuntimeProfile) -> RuntimeProfile:
         if fresh:
             os.environ["XLA_FLAGS"] = (existing + " " + " ".join(fresh)).strip()
     if profile.platform is not None:
+        prev = jax.config.read("jax_platform_name")
         jax.config.update("jax_platform_name", profile.platform)
+        try:
+            check_platform(profile)
+        except RuntimeError:
+            jax.config.update("jax_platform_name", prev)
+            raise
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
     jax.config.update("jax_debug_nans", bool(profile.nan_debug))
     jax.config.update("jax_enable_x64", bool(profile.x64))
     _ACTIVE = profile
     return profile
+
+
+def check_platform(profile: RuntimeProfile) -> None:
+    """Raise ``RuntimeError`` unless the first device is on the platform
+    ``profile`` names (no-op for autodetecting profiles)."""
+    import jax
+
+    if profile.platform is None:
+        return
+    try:
+        got = jax.devices()[0].platform
+    except RuntimeError as e:            # the named backend is absent
+        raise RuntimeError(
+            f"runtime profile {profile.name!r} needs platform "
+            f"{profile.platform!r}, which failed to start: {e}") from None
+    if got != profile.platform:
+        raise RuntimeError(
+            f"runtime profile {profile.name!r} needs platform "
+            f"{profile.platform!r} but the first device is on {got!r}")
 
 
 def active() -> RuntimeProfile:
@@ -227,6 +265,7 @@ def stamp(profile: Optional[RuntimeProfile] = None) -> dict:
     import jax
 
     p = profile or active()
+    check_platform(p)
     backend = jax.default_backend()
     dev = jax.devices()[0]
     return {

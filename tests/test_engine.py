@@ -136,6 +136,23 @@ def test_fused_topk_l2_padding_never_wins():
     assert np.asarray(i2).max() < 1000 and np.asarray(i2).min() >= 0
 
 
+def test_fused_merge_orders_ties_by_id():
+    """Equal scores leave the in-kernel merge lower id first, whichever
+    lane holds them — the order ``lax.top_k`` gives over an id-ordered
+    scan, so fused, XLA and sharded plans agree bit for bit.  Once the
+    candidates run out, the merge pads with (NEG, -1)."""
+    from repro.kernels.fused_topk import NEG, _merge_tile
+
+    best_s = jnp.array([[5.0] + [NEG] * 4])          # the running [1, k]
+    best_i = jnp.array([[900] + [-1] * 4], jnp.int32)
+    s = jnp.array([[5.0, 7.0, 5.0, NEG]])
+    ids = jnp.array([[3, 8, 1, -1]], jnp.int32)
+    out_s, out_i = _merge_tile(best_s, best_i, s, ids, 5)
+    np.testing.assert_array_equal(np.asarray(out_s),
+                                  [[7.0, 5.0, 5.0, 5.0, NEG]])
+    np.testing.assert_array_equal(np.asarray(out_i), [[8, 1, 3, 900, -1]])
+
+
 # --------------------------------------------------------------------------
 # engine.topk over stores: precision arms agree with exact search
 # --------------------------------------------------------------------------
@@ -185,6 +202,28 @@ def test_engine_store_base_rebases_ids(corpus_queries):
     _s, i, _ = engine.topk(queries, st, 5, "ip")
     ids = np.asarray(i)
     assert ids.min() >= 10_000 and ids.max() < 10_000 + corpus.shape[0]
+
+
+@pytest.mark.parametrize("block", [1, 3, 16])
+def test_query_blocks_equal_one_block(corpus_queries, block, monkeypatch):
+    """Candidate scans run over query blocks, which bound the gathered
+    rows; any block size, padded or not, gives the one-block answer."""
+    from repro.engine import scorer
+
+    corpus, queries = corpus_queries
+    params = Qz.learn_params(corpus, bits=8, scheme="gaussian", sigmas=3.0)
+    store = engine.CodeStore.from_codes(Qz.quantize(corpus, params), params)
+    qq = store.encode_queries(queries)
+    cand = jax.random.randint(jax.random.PRNGKey(2), (16, 50), -1, 900)
+
+    def fn(q, c):
+        return engine.topk_among(q, store, c, 10, "l2")
+
+    want = fn(qq, cand)
+    monkeypatch.setattr(scorer, "GATHER_BYTES", block * 4 * 50 * qq.shape[1])
+    got = engine.by_query_block(fn, qq, cand)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_engine_odd_dim_packs(corpus_queries):

@@ -6,6 +6,10 @@ the rebuilt serve loop, and the bench trend gate."""
 
 import io
 import json
+import os
+import shutil
+import subprocess
+import sys
 import warnings
 
 import jax
@@ -34,6 +38,7 @@ from repro.runtime import profile as rtprofile
 
 K = 10
 D = 24
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -128,6 +133,88 @@ class TestRuntimeProfile:
             assert rtprofile.resolve("_test_prof") is p
         finally:
             rtprofile.PROFILES.pop("_test_prof")
+
+    def test_platform_profile_refuses_other_backend(self):
+        """tpu-serve on a CPU backend raises instead of falling back, and
+        leaves the process on the platform it had."""
+        rtprofile._reset_for_tests()
+        before = jax.config.read("jax_platform_name")
+        try:
+            with pytest.raises(RuntimeError, match="tpu"):
+                rtprofile.apply(rtprofile.resolve("tpu-serve"))
+            assert rtprofile._ACTIVE is None
+            assert jax.config.read("jax_platform_name") == before
+            with pytest.raises(RuntimeError, match="tpu"):
+                rtprofile.stamp(rtprofile.resolve("tpu-serve"))
+        finally:
+            rtprofile._reset_for_tests()
+
+    def test_compile_cache_placement(self, monkeypatch):
+        """The cache goes where JAX_COMPILATION_CACHE_DIR says; without
+        it, to one fixed directory inside the checkout."""
+        prev = jax.config.jax_compilation_cache_dir
+        rtprofile._reset_for_tests()
+        try:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            rtprofile.apply(rtprofile.resolve("default"))
+            assert jax.config.jax_compilation_cache_dir == os.path.join(
+                ROOT, ".jax_cache")
+            rtprofile._reset_for_tests()
+            jax.config.update("jax_compilation_cache_dir", prev)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+            rtprofile.apply(rtprofile.resolve("default"))
+            assert jax.config.jax_compilation_cache_dir == prev
+        finally:
+            rtprofile._reset_for_tests()
+            jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def _run_py(args, cwd, pythonpath=True):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    if pythonpath:
+        env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    return subprocess.run([sys.executable, *args], cwd=cwd, env=env,
+                          capture_output=True, text=True, timeout=300)
+
+
+def _printed_result(out: str) -> bool:
+    lines = out.strip().splitlines()
+    return bool(lines) and lines[-1].startswith('{"ok"')
+
+
+class TestNoSilentFallback:
+    """The chip entry points fail loudly on a CPU-only backend."""
+
+    def test_serve_tpu_profile_exits_nonzero(self):
+        r = _run_py(["-m", "repro.launch.serve", "--profile", "tpu-serve",
+                     "--n", "64", "--d", "8", "--requests", "1"], ROOT)
+        assert r.returncode != 0
+        assert "tpu" in r.stderr and "QPS" not in r.stdout
+
+    def test_chip_smoke_exits_nonzero(self):
+        r = _run_py(["chip_smoke.py"], ROOT)
+        assert r.returncode != 0 and not _printed_result(r.stdout)
+        assert "tpu" in r.stderr
+
+    def test_serve_path_leaves_xla_flags_alone(self):
+        """launch/dryrun.py sets XLA_FLAGS when imported; nothing the
+        chip check imports may pull it in."""
+        r = _run_py(["-c", (
+            "import os, sys; before = os.environ.get('XLA_FLAGS'); "
+            "from repro.launch import serve; from repro import engine; "
+            "from repro.runtime import profile; "
+            "assert 'repro.launch.dryrun' not in sys.modules; "
+            "assert os.environ.get('XLA_FLAGS') == before")], ROOT)
+        assert r.returncode == 0, r.stderr[-2000:]
+
+    def test_chip_smoke_alone_exits_nonzero(self, tmp_path):
+        """Copied out of the checkout, the script finds no program to
+        drive: it fails on the missing package and prints no result."""
+        shutil.copy(os.path.join(ROOT, "chip_smoke.py"), tmp_path)
+        r = _run_py(["chip_smoke.py"], str(tmp_path), pythonpath=False)
+        assert r.returncode != 0 and not _printed_result(r.stdout)
+        assert "No module named 'repro'" in r.stderr
 
 
 # ---------------------------------------------------------------------------
@@ -534,6 +621,22 @@ class TestServeLoop:
             e.get("cache") == "hit" for e in tel["events"]
             if e["type"] == "request")
         assert c["queries_served"] > 0
+
+    def test_failed_maintenance_exits_nonzero(self, monkeypatch):
+        """A background round that raises is counted, and the session
+        then exits non-zero instead of reporting success."""
+        from repro.launch import serve
+
+        def broken(self):
+            raise RuntimeError("injected maintenance fault")
+
+        monkeypatch.setattr(MaintenanceScheduler, "run_once", broken)
+        with pytest.raises(SystemExit, match="maintenance"):
+            serve.main([
+                "--index", "stream(flat,lpq8)", "--n", "300", "--d", "16",
+                "--requests", "6", "--batch", "8", "--maintenance",
+                "--maintenance-interval", "0.001",
+            ])
 
     def test_overload_degrades_and_sheds_cleanly(self, tmp_path):
         from repro.launch import serve

@@ -97,6 +97,26 @@ def test_same_bucket_calls_do_not_retrace(corpus_queries, built):
     assert searcher.trace_counts == {8: 1, 32: 1}
 
 
+# the graph walks close over their store inside the jitted beam search,
+# below the Searcher, so their buckets still carry it as a constant
+@pytest.mark.parametrize("kind", ["flat", "ivf", "pq"])
+def test_bucket_takes_the_index_as_arguments(kind, built):
+    """The compiled bucket receives the index's arrays as arguments: jit
+    would otherwise bake them into the program as constants, a copy of
+    the corpus per bucket."""
+    idx = built[kind]
+    lowered = idx.searcher(K, SP, batch_sizes=(8,)).lower(8)
+    n_args = len(jax.tree.leaves(lowered.args_info))
+    assert n_args >= 2, kind                     # queries + index arrays
+    rows = idx.store.data if hasattr(idx.store, "data") else idx.store.codes
+    shape = "x".join(str(x) for x in rows.shape)
+    dtype = {"int8": "i8", "uint8": "ui8", "float32": "f32"}[str(rows.dtype)]
+    assert f"tensor<{shape}x{dtype}>" in lowered.as_text(), kind
+    assert "dense<" not in "".join(
+        line for line in lowered.as_text().splitlines()
+        if f"tensor<{shape}x" in line and "constant" in line), kind
+
+
 def test_oversized_requests_run_in_max_bucket_slices(corpus_queries, built):
     _corpus, queries = corpus_queries
     idx = built["flat"]
@@ -243,6 +263,30 @@ def test_sharded_plan_every_kind_matches_unsharded(corpus_queries, built):
         assert sh.stats["placement"] in (
             "rows", "lists", "segments", "replicated"
         ), kind
+
+
+def test_ivf_gather_in_query_blocks_matches(corpus_queries, built,
+                                            monkeypatch):
+    """Under a tiny gather budget the ivf fine scan runs one query per
+    block, unsharded and list-placed, and answers exactly as in one
+    block."""
+    from repro.engine import scorer
+
+    _corpus, queries = corpus_queries
+    idx = built["ivf"]
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    want = idx.searcher(K, SP)(queries)
+    monkeypatch.setattr(scorer, "GATHER_BYTES", 1)
+    jax.clear_caches()
+    try:
+        for shards in (None, mesh):
+            got = idx.searcher(K, SP, shards=shards)(queries)
+            np.testing.assert_array_equal(np.asarray(want.ids),
+                                          np.asarray(got.ids))
+            np.testing.assert_array_equal(np.asarray(want.scores),
+                                          np.asarray(got.scores))
+    finally:
+        jax.clear_caches()
 
 
 def test_sharded_plan_rejects_mismatched_placement(corpus_queries, built):
