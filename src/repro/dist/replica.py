@@ -19,6 +19,7 @@ Two layers, matching the two places replication happens:
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -155,18 +156,18 @@ class ReplicaSet:
                 q.task_done()
                 return
             payload, nq, fut, seq, t_enq = item
-            t0 = time.perf_counter()
             tr = None
             if self._telemetry is not None:
                 tr = self._telemetry.request(seq)
-                tr.phase(f"replica{r}/queue_wait", t0 - t_enq)
-            try:
-                res = self._replicas[r](payload)
-                fut.set_result(res)
-            except BaseException as e:  # surface on the future, keep serving
-                fut.set_exception(e)
+                tr.phase(f"replica{r}/queue_wait", time.perf_counter() - t_enq)
+            with (tr.span(f"replica{r}/execute") if tr is not None
+                  else contextlib.nullcontext()):
+                try:
+                    res = self._replicas[r](payload)
+                    fut.set_result(res)
+                except BaseException as e:  # to the future; serve on
+                    fut.set_exception(e)
             if tr is not None:
-                tr.phase(f"replica{r}/execute", time.perf_counter() - t0)
                 tr.annotate(replica=r, queries=nq, outcome="served")
                 tr.finish()
             with self._lock:

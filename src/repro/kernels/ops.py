@@ -271,14 +271,16 @@ def fused_topk(
         qe, qo = _split_nibble_queries(q)
         qe = _pad_rows(qe, _round_up(Q, bq))
         qo = _pad_rows(qo, _round_up(Q, bq))
-        xp = _pad_rows(x, _round_up(N, bn))
+        with jax.named_scope("kernels.pad_codes"):
+            xp = _pad_rows(x, _round_up(N, bn))
         s, i = _fused.fused_topk4_pallas(
             qe, qo, xp, k=k, metric=metric, n_valid=N,
             bq=bq, bn=bn, interpret=interp, mask=mp,
         )
     else:
         qp = _pad_rows(q, _round_up(Q, bq))
-        xp = _pad_rows(x, _round_up(N, bn))
+        with jax.named_scope("kernels.pad_codes"):
+            xp = _pad_rows(x, _round_up(N, bn))
         s, i = _fused.fused_topk_pallas(
             qp, xp, k=k, metric=metric, n_valid=N,
             bq=bq, bn=bn, interpret=interp, mask=mp,

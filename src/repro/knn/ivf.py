@@ -240,45 +240,49 @@ class IVFIndex:
 
             # 1) coarse: engine top-k over the (tiny, always-fp32)
             #    centroid store
-            _cs, probe, _ = engine.topk(
-                qf, engine.CodeStore.dense(self.centroids), nprobe,
-                self.metric, mask=lmask,
-            )
+            with jax.named_scope("ivf.coarse"):
+                _cs, probe, _ = engine.topk(
+                    qf, engine.CodeStore.dense(self.centroids), nprobe,
+                    self.metric, mask=lmask,
+                )
 
             # 2) gather candidate ids -> [Q, nprobe * max_list]; a fully
             #    masked-out probe slot (id -1 under the list skip) yields
             #    -1 candidates, dead at the fine-score fence
-            if lmask is None:
-                cand = self.lists[probe].reshape(qq.shape[0], -1)
-            else:
-                probe_ok = probe >= 0
-                cand = jnp.where(
-                    probe_ok[..., None],
-                    self.lists[jnp.clip(probe, 0, self.nlist - 1)], -1,
-                ).reshape(qq.shape[0], -1)
+            with jax.named_scope("ivf.gather"):
+                if lmask is None:
+                    cand = self.lists[probe].reshape(qq.shape[0], -1)
+                else:
+                    probe_ok = probe >= 0
+                    cand = jnp.where(
+                        probe_ok[..., None],
+                        self.lists[jnp.clip(probe, 0, self.nlist - 1)], -1,
+                    ).reshape(qq.shape[0], -1)
 
             # 3) fine scoring + top-k through the engine (gather, unpack-
             #    as-needed, mask empties, select).  Regional builds must
             #    dequantize per row — codes from different lists live in
             #    different integer spaces, so raw-code scoring would
             #    silently compare across constant sets.
-            if self.regions is not None:
-                scores, ids = engine.topk_among_regional(
-                    qf, self.store, self.regions.scale, self.regions.zero,
-                    self.regions.assign, cand, k, self.metric, mask=fmask,
-                )
-                stats = {"kind": "ivf", "nprobe": nprobe, "chunks": nprobe,
-                         **engine.regional_stats(self.store, cand)}
-            else:
-                scores, ids = engine.topk_among(
-                    qq, self.store, cand, k, self.metric, mask=fmask
-                )
-                stats = {"kind": "ivf", "nprobe": nprobe,
-                         **engine.search_stats(
-                             self.store,
-                             candidates=nprobe * self.max_list,
-                             chunks=nprobe,
-                             rows_read=qq.shape[0] * nprobe * self.max_list)}
+            with jax.named_scope("ivf.fine"):
+                if self.regions is not None:
+                    scores, ids = engine.topk_among_regional(
+                        qf, self.store, self.regions.scale, self.regions.zero,
+                        self.regions.assign, cand, k, self.metric, mask=fmask,
+                    )
+                    stats = {"kind": "ivf", "nprobe": nprobe, "chunks": nprobe,
+                             **engine.regional_stats(self.store, cand)}
+                else:
+                    scores, ids = engine.topk_among(
+                        qq, self.store, cand, k, self.metric, mask=fmask
+                    )
+                    stats = {"kind": "ivf", "nprobe": nprobe,
+                             **engine.search_stats(
+                                 self.store,
+                                 candidates=nprobe * self.max_list,
+                                 chunks=nprobe,
+                                 rows_read=(qq.shape[0] * nprobe
+                                            * self.max_list))}
             return B.SearchResult(scores, ids, {**stats, **fstats})
 
         return run

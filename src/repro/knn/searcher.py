@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from repro import engine
 from repro.knn import base as B
+from repro.runtime.telemetry import span
 from repro.tune import table as tunetable
 
 __all__ = ["Searcher", "Rerank", "one_shot", "sharded_scan_plan",
@@ -635,50 +636,79 @@ class Searcher:
         return q
 
     def __call__(self, queries) -> B.SearchResult:
-        q = self._validate_queries(queries)
-        if self.batch_sizes is None:                       # one-shot mode
-            res = self._run(q)
-            return B.SearchResult(res.scores, res.ids, {
-                **res.stats, **self._extras,
-                "bucket": int(q.shape[0]), "padded_q": 0,
-            })
+        """Run ``queries`` [Q, d].  Host spans (``runtime.telemetry``)
+        name the phases: ``searcher.call`` around the whole call, and
+        inside it ``searcher.prepare`` (validation, upload, slicing and
+        bucket pad), ``searcher.dispatch`` (executable lookup and launch;
+        ``built=True`` where the lookup missed, so the launch compiles),
+        ``searcher.wait`` (the read of a slice's stats, the one point in
+        the call where the host may block on the device; stats computed
+        from shapes alone are ready at once, and then the caller's copy
+        of the answer is the first block) and ``searcher.assemble``."""
+        with span("searcher.call") as call:
+            with span("searcher.prepare"):
+                q = self._validate_queries(queries)
+                total = int(q.shape[0])
+                slices = self._slices(q)
+            call.update(queries=total, slices=len(slices))
+            if self.batch_sizes is None:                   # one-shot mode
+                with span("searcher.dispatch"):
+                    res = self._run(q)
+                return B.SearchResult(res.scores, res.ids, {
+                    **res.stats, **self._extras,
+                    "bucket": total, "padded_q": 0,
+                })
 
+            parts_s, parts_i = [], []
+            padded_q = 0
+            # batch-cumulative keys sum across slices; the remaining stats
+            # (candidates/chunks/reranked: per-query by the engine
+            # contract, identical in every slice) carry over from the last
+            summed = {"bytes_read": 0, "rerank_bytes": 0}
+            stats: dict[str, Any] = {}
+            for sl, rows in slices:
+                with span("searcher.dispatch") as dispatch:
+                    known = len(self._executables)
+                    fn, arrays = self._executable(sl)
+                    if len(self._executables) > known:
+                        dispatch["built"] = True
+                    res = fn(arrays, sl)
+                with span("searcher.assemble"):
+                    parts_s.append(res.scores[:rows])
+                    parts_i.append(res.ids[:rows])
+                    padded_q += int(sl.shape[0]) - rows
+                with span("searcher.wait"):
+                    for key in summed:
+                        summed[key] += int(res.stats.get(key, 0))
+                stats = dict(res.stats)
+
+            with span("searcher.assemble"):
+                s, i = ((parts_s[0], parts_i[0]) if len(slices) == 1 else
+                        (jnp.concatenate(parts_s), jnp.concatenate(parts_i)))
+                stats.update(self._extras)
+                stats.update(bucket=int(slices[-1][0].shape[0]),
+                             padded_q=padded_q,
+                             bytes_read=summed["bytes_read"])
+                if summed["rerank_bytes"]:
+                    stats["rerank_bytes"] = summed["rerank_bytes"]
+            return B.SearchResult(s, i, stats)
+
+    def _slices(self, q: jax.Array) -> list[tuple[jax.Array, int]]:
+        """``q`` cut into slices of at most the largest bucket, each
+        padded up to its bucket: [(padded slice, real rows), ...]."""
+        if self.batch_sizes is None:
+            return [(q, int(q.shape[0]))]
         total = int(q.shape[0])
         max_b = self.batch_sizes[-1]
-        parts_s, parts_i = [], []
-        padded_q = 0
-        # batch-cumulative keys sum across slices; the remaining stats
-        # (candidates/chunks/reranked: per-query by the engine contract,
-        # identical in every slice) carry over from the last one
-        summed = {"bytes_read": 0, "rerank_bytes": 0}
-        stats: dict[str, Any] = {}
-        bucket = max_b
-        start = 0
-        while start < total:
-            stop = min(start + max_b, total)
-            sl = q[start:stop]
-            rows = stop - start
+        out = []
+        for start in range(0, total, max_b):
+            sl = q[start:start + max_b]
+            rows = int(sl.shape[0])
             bucket = next(b for b in self.batch_sizes if b >= rows)
             if bucket > rows:
                 sl = jnp.pad(sl, ((0, bucket - rows), (0, 0)))
-            fn, arrays = self._executable(sl)
-            res = fn(arrays, sl)
-            parts_s.append(res.scores[:rows])
-            parts_i.append(res.ids[:rows])
-            padded_q += bucket - rows
-            for key in summed:
-                summed[key] += int(res.stats.get(key, 0))
-            stats = dict(res.stats)
-            start = stop
-
-        s = parts_s[0] if len(parts_s) == 1 else jnp.concatenate(parts_s)
-        i = parts_i[0] if len(parts_i) == 1 else jnp.concatenate(parts_i)
-        stats.update(self._extras)
-        stats.update(bucket=bucket, padded_q=padded_q,
-                     bytes_read=summed["bytes_read"])
-        if summed["rerank_bytes"]:
-            stats["rerank_bytes"] = summed["rerank_bytes"]
-        return B.SearchResult(s, i, stats)
+            out.append((sl, rows))
+        return out
 
 
 def one_shot(index, queries, k: int, params: Optional[B.SearchParams]) -> B.SearchResult:

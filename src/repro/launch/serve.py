@@ -24,7 +24,9 @@ core, ``repro.runtime`` supplies the production machinery:
     (snapshot -> off-lock build -> atomic manifest swap), so a
     ``compact()`` never blocks a query.
   * ``--telemetry-out`` — the structured event log (per-request
-    queue-wait/execute spans, shared cache/admission counters) as JSON.
+    queue-wait/execute spans, shared cache/admission counters) as JSON,
+    with span records kept for the session: every Searcher call's
+    span tree (``searcher.call`` and its phases).
   * ``--tune`` / ``--index-path`` / ``--save-index`` — measured-dispatch
     plumbing (DESIGN.md §13): adopt a standalone TuneTable JSON, load a
     saved index (its embedded table adopted, stamp-checked), or save the
@@ -202,10 +204,20 @@ def main(argv: list[str] | None = None) -> dict:
     ``index``, ``searcher`` (the first replica's primary plan),
     ``replica_searchers``, ``corpus``, ``queries``, ``build_s`` and
     ``warm_s`` (bucket compilation and first calls).  Exits non-zero
-    when a background maintenance round failed.
+    when a background maintenance round failed.  With
+    ``--telemetry-out`` span records are kept for the whole session, so
+    the JSON holds every Searcher call's span tree.
     """
     args = _parse_args(argv)
+    if not args.telemetry_out:
+        return _serve(args)
+    from repro.runtime.telemetry import recording
 
+    with recording():
+        return _serve(args)
+
+
+def _serve(args) -> dict:
     # profile first: platform/XLA/core-pinning are process-start state
     prof = rtprofile.apply(
         rtprofile.from_file(args.profile_file) if args.profile_file
@@ -552,7 +564,7 @@ def main(argv: list[str] | None = None) -> dict:
     writes = 0
     seq = 0
     t0 = time.perf_counter()
-    pending = []       # replica mode: (future, n_queries, degraded)
+    pending = []       # replica mode: (future, n_queries)
     while queue:
         op, payload, vecs, timing, decision = queue.popleft()
         t_req = time.perf_counter()
@@ -577,7 +589,7 @@ def main(argv: list[str] | None = None) -> dict:
             fut.add_done_callback(
                 lambda _f, t=t_sub: latencies.append(time.perf_counter() - t)
             )
-            pending.append((fut, int(payload.shape[0]), degraded))
+            pending.append((fut, int(payload.shape[0])))
             continue
         if op == "query":
             t_enq, deadline = timing
@@ -604,8 +616,6 @@ def main(argv: list[str] | None = None) -> dict:
                 totals[key] += int(res.stats.get(key, 0))
             hit = res.stats.get("cache") == "hit"
             telemetry.counters["queries_served"] += int(payload.shape[0])
-            if degraded:
-                telemetry.counters["requests_degraded"] += 1
             if filt is not None:
                 telemetry.counters["filtered_requests"] += 1
                 telemetry.counters["filtered_queries"] += int(
@@ -634,8 +644,7 @@ def main(argv: list[str] | None = None) -> dict:
                 index.upsert(payload, vecs)
             else:
                 index.delete(payload)
-            replanned = epoch_before is None or index.epoch != epoch_before
-            if replanned:
+            if epoch_before is None or index.epoch != epoch_before:
                 replan_gen[0] += 1
                 if replicas is not None:
                     # every replica re-plans (and re-warms) against the
@@ -653,19 +662,14 @@ def main(argv: list[str] | None = None) -> dict:
                 telemetry.counters["replans_avoided"] += 1
             write_latencies.append(time.perf_counter() - t_req)
             writes += len(payload)
-            telemetry.event("write", op=op, rows=int(len(payload)),
-                            replanned=replanned, epoch=index.epoch
-                            if epoch_before is not None else None)
     if replicas is not None:
         replicas.drain()
-        for fut, nq, degraded in pending:
+        for fut, nq in pending:
             res = fut.result()
             served += nq
             for key in _AGG_KEYS:
                 totals[key] += int(res.stats.get(key, 0))
             telemetry.counters["queries_served"] += nq
-            if degraded:
-                telemetry.counters["requests_degraded"] += 1
             if filt is not None:
                 telemetry.counters["filtered_requests"] += 1
                 telemetry.counters["filtered_queries"] += nq

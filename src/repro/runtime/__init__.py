@@ -3,8 +3,9 @@
 # artifact (profile), hot-path result/LUT caching (cache), token-bucket
 # admission control with a degrade/shed ladder and deadline propagation
 # (admission), background compaction + drift recalibration off the
-# request path (maintenance), and the structured per-request telemetry
-# the serve report and the CI trend gate consume (telemetry).
+# request path (maintenance), and the structured telemetry: counters,
+# per-request phases and host spans on the profiler's clock, which the
+# serve report and ``serve.py --telemetry-out`` write (telemetry).
 from repro.runtime import profile
 from repro.runtime.admission import (
     ADMIT,

@@ -10,6 +10,7 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 import warnings
 
 import jax
@@ -35,6 +36,7 @@ from repro.runtime import (
     fingerprint,
 )
 from repro.runtime import profile as rtprofile
+from repro.runtime import telemetry
 
 K = 10
 D = 24
@@ -479,6 +481,60 @@ class TestTelemetry:
         assert set(json.loads(out.read_text())) == {
             "meta", "counters", "summary", "events"}
 
+    def test_spans_kept_only_while_recording(self):
+        t0 = time.perf_counter_ns()
+        with telemetry.span("outside"):
+            pass
+        assert telemetry.recorded_spans(t0) == []
+        with telemetry.recording():
+            with telemetry.span("outer", a=1) as fields:
+                fields["b"] = 2
+                with telemetry.span("inner"):
+                    pass
+        with telemetry.span("after"):
+            pass
+        inner, outer = sorted(telemetry.recorded_spans(t0),
+                              key=lambda r: r["name"])
+        assert [outer["name"], inner["name"]] == ["outer", "inner"]
+        assert outer["fields"] == {"a": 1, "b": 2}
+        assert outer["parent"] is None and inner["parent"] == outer["id"]
+        assert inner["request"] == outer["request"] == outer["id"]
+        assert (outer["start_ns"] <= inner["start_ns"] <= inner["end_ns"]
+                <= outer["end_ns"])
+
+    def test_spans_kept_under_a_profiler_session(self, tmp_path):
+        t0 = time.perf_counter_ns()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with telemetry.span("profiled"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        with telemetry.span("unprofiled"):
+            pass
+        assert [r["name"] for r in telemetry.recorded_spans(t0)] == [
+            "profiled"]
+
+    def test_to_json_adds_the_session_spans(self):
+        clk = FakeClock()
+        t = Telemetry(clock=clk)
+        with telemetry.recording():
+            with t.span("maintenance/compact", trigger="drift"):
+                clk.advance(0.5)
+            tr = t.request(7)
+            with tr.span("execute"):
+                pass
+            tr.finish()
+        payload = t.to_json(io.StringIO())
+        kinds = [e["type"] for e in payload["events"]]
+        assert kinds == ["span", "request", "host_span", "host_span"]
+        compact, execute = payload["events"][2:]
+        assert compact["name"] == "maintenance/compact"
+        assert compact["fields"] == {"trigger": "drift"}
+        assert execute["name"] == "execute"
+        assert execute["fields"] == {"req_id": 7}
+        assert payload["events"][0]["dur_s"] == pytest.approx(0.5)
+
 
 # ---------------------------------------------------------------------------
 # background compaction + maintenance
@@ -621,6 +677,13 @@ class TestServeLoop:
             e.get("cache") == "hit" for e in tel["events"]
             if e["type"] == "request")
         assert c["queries_served"] > 0
+        # the session recorded every Searcher call's span tree
+        spans = [e for e in tel["events"] if e["type"] == "host_span"]
+        calls = {e["id"] for e in spans if e["name"] == "searcher.call"}
+        assert calls
+        for e in spans:
+            if e["name"].startswith("searcher.") and e["id"] not in calls:
+                assert e["parent"] in calls
 
     def test_failed_maintenance_exits_nonzero(self, monkeypatch):
         """A background round that raises is counted, and the session

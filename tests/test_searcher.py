@@ -8,6 +8,7 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from repro.knn import (
     make_index,
     parse_factory,
 )
+from repro.runtime import telemetry
 
 K = 10
 
@@ -95,6 +97,82 @@ def test_same_bucket_calls_do_not_retrace(corpus_queries, built):
     searcher(queries[:20])                       # bucket 32: one new trace
     searcher(queries[:32])
     assert searcher.trace_counts == {8: 1, 32: 1}
+
+
+def _call_tree(spans):
+    """{call id: (call record, [its children])} of ``searcher.call`` spans."""
+    calls = {r["id"]: (r, []) for r in spans if r["name"] == "searcher.call"}
+    for r in spans:
+        if r["parent"] in calls:
+            calls[r["parent"]][1].append(r)
+    return calls
+
+
+def test_call_spans_nest_under_one_request(corpus_queries, built):
+    """Each call records one ``searcher.call`` holding its phases, all of
+    one request; ``built`` marks only a bucket's first dispatch."""
+    _corpus, queries = corpus_queries
+    searcher = built["flat"].searcher(K, SP, batch_sizes=(8, 32))
+    t0 = time.perf_counter_ns()
+    with telemetry.recording():
+        for n in (5, 5, 20, 40):
+            searcher(queries[:n] if n <= 32 else jnp.tile(queries, (2, 1))[:n])
+    spans = telemetry.recorded_spans(t0)
+    calls = _call_tree(spans)
+    assert len(calls) == 4
+    assert all(r["parent"] in calls for r in spans
+               if r["name"] != "searcher.call")
+    built_flags = []
+    for call, children in sorted(calls.values(), key=lambda c: c[0]["id"]):
+        assert call["parent"] is None
+        assert {c["request"] for c in children} == {call["request"]}
+        assert all(call["start_ns"] <= c["start_ns"] <= c["end_ns"]
+                   <= call["end_ns"] for c in children)
+        names = [c["name"] for c in sorted(children, key=lambda c: c["id"])]
+        slices = call["fields"]["slices"]
+        assert names == (["searcher.prepare"]
+                         + ["searcher.dispatch", "searcher.assemble",
+                            "searcher.wait"] * slices
+                         + ["searcher.assemble"])
+        built_flags.append([c["fields"].get("built", False)
+                            for c in children
+                            if c["name"] == "searcher.dispatch"])
+    assert [c[0]["fields"] for c in sorted(calls.values(),
+                                           key=lambda c: c[0]["id"])] == [
+        {"queries": 5, "slices": 1}, {"queries": 5, "slices": 1},
+        {"queries": 20, "slices": 1}, {"queries": 40, "slices": 2}]
+    # bucket 8 built on the first call, 32 on the third; 40 queries run
+    # as 32 + 8, both built already
+    assert built_flags == [[True], [False], [True], [False, False]]
+
+
+def test_calls_keep_no_spans_while_recording_is_off(corpus_queries, built):
+    _corpus, queries = corpus_queries
+    searcher = built["flat"].searcher(K, SP, batch_sizes=(8,))
+    t0 = time.perf_counter_ns()
+    searcher(queries[:5])
+    assert telemetry.recorded_spans(t0) == []
+
+
+def test_named_scopes_reach_the_compiled_ops(built, monkeypatch):
+    """The ivf plan's phases and the fused kernel's codes pad carry their
+    ``named_scope`` into the compiled program's op metadata."""
+    text = built["ivf"].searcher(K, SP, batch_sizes=(8,)).lower(8) \
+        .compile().as_text()
+    for name in ("ivf.coarse", "ivf.gather", "ivf.fine"):
+        assert f"/{name}/" in text, name
+    # steer the flat scan onto the fused kernel, in interpret mode: the
+    # engine takes it only on a TPU
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    idx = make_index("flat,lpq8", np.asarray(
+        jax.random.normal(jax.random.PRNGKey(2), (1000, 32))))
+    text = idx.searcher(K, SearchParams(chunk=256), batch_sizes=(8,)) \
+        .lower(8).compile().as_text()
+    assert "fused_topk" in text
+    assert "/kernels.pad_codes/" in text
 
 
 # the graph walks close over their store inside the jitted beam search,
