@@ -281,7 +281,10 @@ def topk(
     Dispatch consults the installed TuneTable first (``repro.tune``):
     a matching entry decides fused-vs-scan and the tile/chunk shapes;
     on a miss, today's constants apply unchanged.  ``stats["tuned"]``
-    records which happened.
+    records which happened.  The fused kernel's stats add
+    ``merge_steps`` and ``merge_tiles`` (its top-k merge steps and the
+    corpus tiles it visited, over all query tiles) as int32 device
+    scalars, so reading them is the caller's choice of when to wait.
     """
     if isinstance(store, PQStore):
         if metric == "angular":
@@ -344,11 +347,14 @@ def topk(
         and (cfg is None or cfg.impl == "fused")
         and (bool(interpret) or jax.default_backend() == "tpu")
     )
+    merge = {}
     if fused:
-        s, i = K.fused_topk(
+        s, i, (steps, tiles) = K.fused_topk(
             q, store.data, k_eff, metric, packed=store.packed,
-            bq=bq, bn=tile, interpret=interpret, mask=mask,
+            bq=bq, bn=tile, interpret=interpret, mask=mask, merge_counts=True,
         )
+        # the kernel's merge counter, left on the device
+        merge = {"merge_steps": steps, "merge_tiles": tiles}
         chunks = -(-store.n // tile)
         # the fused grid re-streams the corpus once per bq-row query tile
         # (queries are VMEM-resident within a tile, not across tiles)
@@ -366,7 +372,7 @@ def topk(
     stats = search_stats(store, candidates=store.n, chunks=chunks,
                          rows_read=store.n * passes)
     stats["tuned"] = cfg is not None
-    return s, i, stats
+    return s, i, {**stats, **merge}
 
 
 # --------------------------------------------------------------------------

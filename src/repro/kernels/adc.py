@@ -23,7 +23,7 @@ the even/odd LUT halves with no in-kernel interleave:
 
     s = lut_even . onehot(lo)^T + lut_odd . onehot(hi)^T
 
-The scored tile feeds the k-step select-and-mask merge of
+The scored tile feeds the running top-k merge of
 ``fused_topk`` (the [bq, k] best set rides in the output block), so the
 [Q, N] ADC matrix never exists in HBM.  Pure-jnp oracles live in
 :mod:`repro.kernels.ref` (``adc_ref`` / ``adc4_ref``) and deliberately
@@ -105,9 +105,10 @@ def fused_adc_pallas(
     Streaming fused ADC + top-k; rows with id >= ``n_valid`` (padding)
     are masked in-kernel, as is an optional [N] predicate ``mask``.
     """
-    return _fused_call(make_adc_tile(n_codewords), [lut2d], codes,
-                       k=k, n_valid=n_valid, bq=bq, bn=bn,
-                       interpret=interpret, mask=mask)
+    s, i, _counts = _fused_call(make_adc_tile(n_codewords), [lut2d], codes,
+                                k=k, n_valid=n_valid, bq=bq, bn=bn,
+                                interpret=interpret, mask=mask)
+    return s, i
 
 
 @functools.partial(
@@ -130,6 +131,8 @@ def fused_adc4_pallas(
     """Packed-nibble variant: [Q, (M/2)*K] int8 LUT planes x [N, M/2]
     uint8 packed codes -> top-k, unpacking two-codewords-per-byte
     in-kernel."""
-    return _fused_call(make_adc4_tile(n_codewords), [lut_even, lut_odd],
-                       packed, k=k, n_valid=n_valid, bq=bq, bn=bn,
-                       interpret=interpret, mask=mask)
+    s, i, _counts = _fused_call(make_adc4_tile(n_codewords),
+                                [lut_even, lut_odd], packed, k=k,
+                                n_valid=n_valid, bq=bq, bn=bn,
+                                interpret=interpret, mask=mask)
+    return s, i

@@ -213,7 +213,7 @@ def ql24(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "metric", "packed", "bq", "bn", "use_pallas",
-                     "interpret"),
+                     "interpret", "merge_counts"),
 )
 def fused_topk(
     q: jax.Array,
@@ -227,6 +227,7 @@ def fused_topk(
     use_pallas: bool = True,
     interpret: bool | None = None,
     mask: jax.Array | None = None,
+    merge_counts: bool = False,
 ):
     """Streaming fused score + top-k: ([Q, k] f32 scores, [Q, k] i32 ids).
 
@@ -241,6 +242,9 @@ def fused_topk(
     The [Q, N] score matrix never reaches HBM on the Pallas path;
     ``use_pallas=False`` is the XLA reference (materializes scores, used
     for parity tests and as the shard_map cell fallback).
+    With ``merge_counts=True`` a third item follows: the kernel's
+    (merge steps, corpus tiles visited) summed over its query tiles, as
+    int32 device scalars (None on the reference, which merges nothing).
     """
     assert metric in ("ip", "l2"), metric
     Q = q.shape[0]
@@ -259,7 +263,8 @@ def fused_topk(
             # the NEG sentinel topk_ref already turns into id -1
             s = jnp.where(mask.astype(bool)[None, :], s.astype(jnp.float32),
                           jnp.finfo(jnp.float32).min)
-        return _ref.topk_ref(s, k, N)
+        out = _ref.topk_ref(s, k, N)
+        return (*out, None) if merge_counts else out
     interp = (not _on_tpu()) if interpret is None else interpret
     bq = _pick_tile(Q, bq or _fused.BQ)
     # an explicit bn is honored (tuned tiles may exceed the constant —
@@ -273,7 +278,7 @@ def fused_topk(
         qo = _pad_rows(qo, _round_up(Q, bq))
         with jax.named_scope("kernels.pad_codes"):
             xp = _pad_rows(x, _round_up(N, bn))
-        s, i = _fused.fused_topk4_pallas(
+        s, i, counts = _fused.fused_topk4_pallas(
             qe, qo, xp, k=k, metric=metric, n_valid=N,
             bq=bq, bn=bn, interpret=interp, mask=mp,
         )
@@ -281,10 +286,12 @@ def fused_topk(
         qp = _pad_rows(q, _round_up(Q, bq))
         with jax.named_scope("kernels.pad_codes"):
             xp = _pad_rows(x, _round_up(N, bn))
-        s, i = _fused.fused_topk_pallas(
+        s, i, counts = _fused.fused_topk_pallas(
             qp, xp, k=k, metric=metric, n_valid=N,
             bq=bq, bn=bn, interpret=interp, mask=mp,
         )
+    if merge_counts:
+        return s[:Q], i[:Q], _fused.merge_counts(counts)
     return s[:Q], i[:Q]
 
 

@@ -115,13 +115,27 @@ class SearchResult:
         return 2
 
 
-# a jax pytree (scores/ids are leaves, stats is static aux data) so jitted
-# callers can return it, as they could the old (scores, ids) tuple
-jax.tree_util.register_pytree_node(
-    SearchResult,
-    lambda r: ((r.scores, r.ids), tuple(sorted(r.stats.items()))),
-    lambda aux, kids: SearchResult(kids[0], kids[1], dict(aux)),
-)
+def _flatten_result(r: SearchResult):
+    # an array's shape stands in for it where a trace returns shapes
+    device = tuple(sorted(k for k, v in r.stats.items()
+                          if isinstance(v, (jax.Array, jax.ShapeDtypeStruct))))
+    static = tuple(sorted((k, v) for k, v in r.stats.items()
+                          if k not in device))
+    return (r.scores, r.ids, *(r.stats[k] for k in device)), (static, device)
+
+
+def _unflatten_result(aux, kids) -> SearchResult:
+    static, device = aux
+    return SearchResult(kids[0], kids[1],
+                        {**dict(static), **dict(zip(device, kids[2:]))})
+
+
+# a jax pytree (scores, ids and device-valued stats such as the fused
+# kernel's merge counter are leaves; the other stats are static aux data)
+# so jitted callers can return it, as they could the old (scores, ids)
+# tuple
+jax.tree_util.register_pytree_node(SearchResult, _flatten_result,
+                                   _unflatten_result)
 
 
 @runtime_checkable

@@ -33,6 +33,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
+import operator
 from typing import Any, Callable, Optional, Sequence, Union
 
 import jax
@@ -52,6 +54,12 @@ __all__ = ["Searcher", "Rerank", "one_shot", "sharded_scan_plan",
 DEFAULT_BATCH_SIZES = (1, 8, 32, 256)
 
 NEG = float(jnp.finfo(jnp.float32).min)
+
+#: stats a plan returns as device values, left unread by the call: the
+#: fused kernel's merge counter (``engine.topk``).  Slices sum them on
+#: the device, and the ``searcher.call`` span's fields carry them too,
+#: for a reader to take to the host once the calls are done.
+DEVICE_STATS = ("merge_steps", "merge_tiles")
 
 PlanFn = Callable[[jax.Array], B.SearchResult]
 
@@ -644,7 +652,9 @@ class Searcher:
         ``searcher.wait`` (the read of a slice's stats, the one point in
         the call where the host may block on the device; stats computed
         from shapes alone are ready at once, and then the caller's copy
-        of the answer is the first block) and ``searcher.assemble``."""
+        of the answer is the first block) and ``searcher.assemble``.
+        ``searcher.call``'s fields hold ``queries``, ``slices`` and, in
+        bucketed calls, the call's ``DEVICE_STATS`` as device values."""
         with span("searcher.call") as call:
             with span("searcher.prepare"):
                 q = self._validate_queries(queries)
@@ -665,6 +675,7 @@ class Searcher:
             # (candidates/chunks/reranked: per-query by the engine
             # contract, identical in every slice) carry over from the last
             summed = {"bytes_read": 0, "rerank_bytes": 0}
+            device_parts: dict[str, list] = {}
             stats: dict[str, Any] = {}
             for sl, rows in slices:
                 with span("searcher.dispatch") as dispatch:
@@ -680,6 +691,10 @@ class Searcher:
                 with span("searcher.wait"):
                     for key in summed:
                         summed[key] += int(res.stats.get(key, 0))
+                for key in DEVICE_STATS:
+                    if key in res.stats:
+                        device_parts.setdefault(key, []).append(
+                            res.stats[key])
                 stats = dict(res.stats)
 
             with span("searcher.assemble"):
@@ -691,6 +706,11 @@ class Searcher:
                              bytes_read=summed["bytes_read"])
                 if summed["rerank_bytes"]:
                     stats["rerank_bytes"] = summed["rerank_bytes"]
+                # summed on the device: an add per extra slice, no wait
+                device = {key: functools.reduce(operator.add, parts)
+                          for key, parts in device_parts.items()}
+                stats.update(device)
+                call.update(device)
             return B.SearchResult(s, i, stats)
 
     def _slices(self, q: jax.Array) -> list[tuple[jax.Array, int]]:

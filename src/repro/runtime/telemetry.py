@@ -237,6 +237,8 @@ class Telemetry:
 
 
 def _scalar(x):
+    if isinstance(x, jax.Array):       # a span's device field, read at last
+        x = np.asarray(x)
     if isinstance(x, (np.integer,)):
         return int(x)
     if isinstance(x, (np.floating,)):

@@ -139,18 +139,159 @@ def test_fused_topk_l2_padding_never_wins():
 def test_fused_merge_orders_ties_by_id():
     """Equal scores leave the in-kernel merge lower id first, whichever
     lane holds them — the order ``lax.top_k`` gives over an id-ordered
-    scan, so fused, XLA and sharded plans agree bit for bit.  Once the
-    candidates run out, the merge pads with (NEG, -1)."""
-    from repro.kernels.fused_topk import NEG, _merge_tile
+    scan, so fused, XLA and sharded plans agree bit for bit.  The tile's
+    ids all follow the best set's, as the kernel's id-ordered grid makes
+    them; once the candidates run out, the sort pads with (NEG, -1)."""
+    from repro.kernels.fused_topk import NEG, _merge_tile, _sort_best
 
     best_s = jnp.array([[5.0] + [NEG] * 4])          # the running [1, k]
-    best_i = jnp.array([[900] + [-1] * 4], jnp.int32)
+    best_i = jnp.array([[3] + [-1] * 4], jnp.int32)
     s = jnp.array([[5.0, 7.0, 5.0, NEG]])
-    ids = jnp.array([[3, 8, 1, -1]], jnp.int32)
-    out_s, out_i = _merge_tile(best_s, best_i, s, ids, 5)
+    ids = jnp.array([[12, 15, 9, -1]], jnp.int32)
+    bs, bi, steps = _merge_tile(best_s, best_i, s, ids, 5)
+    assert int(steps) == 3                           # three beat the NEG pads
+    out_s, out_i = _sort_best(bs, bi, 5)
     np.testing.assert_array_equal(np.asarray(out_s),
                                   [[7.0, 5.0, 5.0, 5.0, NEG]])
-    np.testing.assert_array_equal(np.asarray(out_i), [[8, 1, 3, 900, -1]])
+    np.testing.assert_array_equal(np.asarray(out_i), [[15, 3, 9, 12, -1]])
+
+
+def test_fused_merge_evicts_the_largest_id_among_the_worst():
+    """A full, unsorted best set: only a tile candidate strictly above the
+    k-th best enters (an equal one has a larger id, so ranks after it),
+    and it evicts the lowest score with the largest id.  One candidate
+    beats the k-th best, so the merge takes one step."""
+    from repro.kernels.fused_topk import NEG, _merge_tile, _sort_best
+
+    best_s = jnp.array([[5.0, 8.0, 5.0]])
+    best_i = jnp.array([[2, 0, 4]], jnp.int32)
+    s = jnp.array([[5.0, 6.0, 5.0, NEG]])
+    ids = jnp.array([[9, 11, 7, -1]], jnp.int32)
+    bs, bi, steps = _merge_tile(best_s, best_i, s, ids, 3)
+    assert int(steps) == 1
+    out_s, out_i = _sort_best(bs, bi, 3)
+    np.testing.assert_array_equal(np.asarray(out_s), [[8.0, 6.0, 5.0]])
+    np.testing.assert_array_equal(np.asarray(out_i), [[0, 11, 2]])
+    # no candidate above the k-th best: no step, the set is unchanged
+    bs, bi, steps = _merge_tile(best_s, best_i, jnp.full_like(s, 5.0), ids, 3)
+    assert int(steps) == 0
+    np.testing.assert_array_equal(np.asarray(bi), np.asarray(best_i))
+
+
+# the corpus orders the data-driven merge must answer exactly in; each is
+# (rows, queries, k, mask rows kept or None)
+MERGE_ORDERS = {
+    "ascending": (1300, 3, 100, None),     # every tile engages k steps
+    "descending": (1300, 3, 100, None),    # only the first tile engages
+    "ties": (1300, 3, 100, None),          # k-th best tied across tiles
+    "few_valid": (37, 3, 50, None),        # fewer rows than k
+    "masked": (1300, 3, 100, 40),          # a mask leaves fewer than k
+    "k1": (1300, 3, 1, None),
+    "k100_ragged": (1300, 130, 100, None),  # N % bn != 0, Q % bq != 0
+}
+MERGE_CODES = {"int8-ip": (8, "ip"), "int8-l2": (8, "l2"),
+               "int4-ip": (4, "ip"), "int4-l2": (4, "l2")}
+
+
+def _full_scores(q, x, metric):
+    return ref.qmip_ref(q, x) if metric == "ip" else ref.ql2_ref(q, x)
+
+
+def _ordered_corpus(order, n, n_q, bits, metric, seed=11):
+    """Codes in ``order`` for query 0 (the others see them at random)."""
+    lo, hi = (-128, 127) if bits == 8 else (-8, 7)
+    kq, kx = jax.random.split(jax.random.PRNGKey(seed))
+    d = 16
+    q = jax.random.randint(kq, (n_q, d), lo, hi + 1, dtype=jnp.int8)
+    x = np.array(jax.random.randint(kx, (n, d), lo, hi + 1, dtype=jnp.int8))
+    if order in ("ascending", "descending"):
+        s0 = np.asarray(_full_scores(q[:1], jnp.asarray(x), metric))[0]
+        rank = np.argsort(s0, kind="stable")
+        x = x[rank if order == "ascending" else rank[::-1]]
+    elif order == "ties":
+        # 300 equal rows straddle the tile boundary at 512: query 0 sits on
+        # them, so they hold its k-th best under l2; under ip only rows
+        # with a larger first code beat them
+        tie = np.zeros(d, np.int8)
+        tie[0] = hi - 1
+        x[400:700] = tie
+        q = q.at[0].set(jnp.asarray(tie))
+    return q, jnp.asarray(x)
+
+
+@pytest.mark.parametrize("codes", sorted(MERGE_CODES))
+@pytest.mark.parametrize("order", sorted(MERGE_ORDERS))
+def test_fused_merge_is_exact(order, codes):
+    """The data-driven merge returns what the XLA reference does, bit for
+    bit (scores and ids), whatever the corpus order: ascending (every
+    tile engages k steps), descending (only the first does), ties at
+    the k-th best across a tile boundary, fewer rows than k, a mask
+    that leaves fewer than k, k = 1, and ragged Q and N at k = 100."""
+    bits, metric = MERGE_CODES[codes]
+    n, n_q, k, kept = MERGE_ORDERS[order]
+    q, x = _ordered_corpus(order, n, n_q, bits, metric)
+    mask = None
+    if kept is not None:
+        mask = jnp.zeros(n, bool).at[
+            jax.random.permutation(jax.random.PRNGKey(3), n)[:kept]].set(True)
+    packed = bits == 4
+    corpus = PK.pack_int4(x) if packed else x
+    got = K.fused_topk(q, corpus, k, metric, packed=packed, mask=mask,
+                       interpret=True)
+    want = K.fused_topk(q, corpus, k, metric, packed=packed, mask=mask,
+                        use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    if kept is not None:
+        assert (np.asarray(got[1])[:, kept:] == -1).all()
+
+
+def _ascending_ip(n):
+    """One query and n int8 rows whose inner products with it are
+    distinct and rise with the row id: 100 * x0 + x1 = id - n // 2."""
+    v = np.arange(n) - n // 2
+    x0 = np.round(v / 100).astype(np.int64)
+    x = np.zeros((n, 8), np.int8)
+    x[:, 0], x[:, 1] = x0, v - 100 * x0
+    q = np.zeros((1, 8), np.int8)
+    q[0, :2] = (100, 1)
+    return jnp.asarray(q), jnp.asarray(x)
+
+
+@pytest.mark.parametrize("n,k", [(1536, 100), (1300, 7), (40, 50)])
+def test_fused_merge_counts_follow_the_order(n, k):
+    """The kernel's counter: descending rows take min(k, valid rows) merge
+    steps, all in the first tile; ascending rows take k in every tile
+    (each tile holds at least k).  Either way every corpus tile is
+    visited once per query tile."""
+    from repro.kernels.fused_topk import BN
+
+    q, x = _ascending_ip(n)
+    tiles = -(-n // BN)
+    for rows, steps in ((x[::-1], min(k, n)),
+                        (x, min(k, n) * tiles)):
+        s, i, (got_steps, got_tiles) = K.fused_topk(
+            q, rows, k, "ip", interpret=True, merge_counts=True)
+        want = K.fused_topk(q, rows, k, "ip", use_pallas=False)
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(want[1]))
+        assert (int(got_steps), int(got_tiles)) == (steps, tiles)
+
+
+def test_fused_kernel_pads_past_the_valid_rows():
+    """Fewer valid rows than k inside the kernel (the tile's tail is
+    padding): the valid rows come back in order, then (NEG, -1) pads,
+    after one merge step per valid row."""
+    from repro.kernels.fused_topk import fused_topk_pallas, merge_counts
+
+    q, x = _ascending_ip(512)
+    qp = jnp.pad(q, ((0, 7), (0, 0)))
+    s, i, counts = fused_topk_pallas(qp, x, k=50, metric="ip", n_valid=30,
+                                     bq=8, bn=512, interpret=True)
+    want_s, want_i = ref.topk_ref(ref.qmip_ref(qp, x), 50, 30)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(want_s))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+    assert (np.asarray(i)[:, 30:] == -1).all()
+    assert [int(c) for c in merge_counts(counts)] == [30, 1]
 
 
 # --------------------------------------------------------------------------

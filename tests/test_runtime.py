@@ -535,6 +535,18 @@ class TestTelemetry:
         assert execute["fields"] == {"req_id": 7}
         assert payload["events"][0]["dur_s"] == pytest.approx(0.5)
 
+    def test_to_json_writes_a_device_field_as_its_number(self):
+        """A span field left on the device (the Searcher's merge counter)
+        is read when the log is written, and written as its number."""
+        t = Telemetry(clock=FakeClock())
+        with telemetry.recording():
+            with telemetry.span("searcher.call") as f:
+                f["merge_steps"] = jax.numpy.int32(130)
+        buf = io.StringIO()
+        t.to_json(buf)
+        event, = json.loads(buf.getvalue())["events"]
+        assert event["fields"] == {"merge_steps": 130}
+
 
 # ---------------------------------------------------------------------------
 # background compaction + maintenance

@@ -175,6 +175,36 @@ def test_named_scopes_reach_the_compiled_ops(built, monkeypatch):
     assert "/kernels.pad_codes/" in text
 
 
+def test_fused_merge_counter_rides_the_stats_unread(monkeypatch):
+    """On the fused kernel's path a call's stats carry the kernel's merge
+    counter as device values, summed over the request's slices, and the
+    ``searcher.call`` span's fields carry the same values; the one-shot
+    search carries them too."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    idx = make_index("flat,lpq8", np.asarray(
+        jax.random.normal(jax.random.PRNGKey(2), (1000, 32))))
+    queries = jax.random.normal(jax.random.PRNGKey(3), (12, 32))
+    searcher = idx.searcher(K, SearchParams(chunk=256), batch_sizes=(8,))
+    t0 = time.perf_counter_ns()
+    with telemetry.recording():
+        res = searcher(queries)                     # two slices of 8
+    call, = [s for s in telemetry.recorded_spans(t0)
+             if s["name"] == "searcher.call"]
+    steps, tiles = res.stats["merge_steps"], res.stats["merge_tiles"]
+    assert isinstance(steps, jax.Array) and isinstance(tiles, jax.Array)
+    assert call["fields"]["merge_steps"] is steps
+    assert call["fields"]["merge_tiles"] is tiles
+    # 4 corpus tiles of 256 rows for each slice's query tile; the first
+    # tile of each takes k steps, and no tile takes more
+    assert int(tiles) == 8
+    assert 2 * K <= int(steps) <= 8 * K
+    one = idx.search(queries[:8], K, SearchParams(chunk=256))
+    assert int(one.stats["merge_tiles"]) == 4
+
+
 # the graph walks close over their store inside the jitted beam search,
 # below the Searcher, so their buckets still carry it as a constant
 @pytest.mark.parametrize("kind", ["flat", "ivf", "pq"])
