@@ -39,6 +39,14 @@ def altered(searcher, setup):
     return call
 
 
+def one_replica(searcher, setup):
+    """The last replica's answers altered as ``altered`` alters them: a
+    wrong copy on one chip, with every other replica sound."""
+    if setup["replica"] != setup["replicas"] - 1:
+        return searcher
+    return altered(searcher, setup)
+
+
 def half_probe(searcher, setup):
     """An ivf plan that probes half the configured lists."""
     nprobe = int(setup["cell"].config["search"]["nprobe"])
@@ -72,4 +80,4 @@ def short_lists(searcher, setup):
 #: the faults each kind of cell can have
 FLAT = (half_batch, altered)
 IVF = FLAT + (half_probe, dropped_list, short_lists)
-BY_NAME = {f.__name__: f for f in IVF}
+BY_NAME = {f.__name__: f for f in IVF + (one_replica,)}

@@ -11,11 +11,21 @@ coarse quantizer back to the host, frees the program's state, and runs
 the reference over a sample of the window's answers drawn from the
 seed, the longest request among them.
 
+A mix whose ``layout`` is ``replicas``, or that asks for C clients
+(``bench/traffic.py``), is served by ``Router``: the index, built once on
+the first chip, is copied to each of the cell's chips, each chip plans
+and warms its own Searcher, and the program's ``ReplicaSet`` routes the
+requests of C closed-loop client threads (``loop.closed_pool``) to the
+least-loaded replica.  At the ``single`` layout and one client the window
+calls the Searcher directly from the calling thread (``loop.closed``).
+
 ``system`` lets a test, a planted fault or the control put something
 else in the Searcher's place: ``system(searcher, setup)`` returns what
-the window calls instead.  ``setup`` holds the data's generator, keys
-and constants, the cell, the index, ``make_searcher`` and the query
-pool.  The benchmark's own runs never pass it.
+the window calls instead, once for each replica.  ``setup`` holds the
+data's generator, keys and constants, the cell, the index (the replica's
+copy), ``make_searcher``, the query pool, and the replica's number, the
+number of replicas and the replica's device.  The benchmark's own runs
+never pass it.
 """
 
 from __future__ import annotations
@@ -26,6 +36,7 @@ import shutil
 import sys
 import tempfile
 import time
+from types import SimpleNamespace
 
 import numpy as np
 
@@ -46,6 +57,10 @@ class RunView:
     device_kind: str
     trace: dict | None = None        # trace.record(...) of the window
     reduced: dict | None = None      # trace.reduce(...) of it
+    replicas: int = 1
+    # the request rows the program's router logged in the window (its
+    # Telemetry's ``request`` events), or None without a router
+    replica_log: list | None = None
 
 
 class NoChip(RuntimeError):
@@ -93,20 +108,86 @@ def _device_memory(devs, key: str) -> int:
 
 
 def _sample(records: list, rng: np.random.Generator, want: int) -> list:
-    """Whole answered requests to compare: the longest first, then others
-    in an order drawn from the seed, until they hold ``want`` queries."""
+    """Whole answered requests to compare: the longest that each replica
+    served first, then others in an order drawn from the seed, until they
+    hold ``want`` queries."""
     done = [i for i, r in enumerate(records) if r["done"] is not None]
     if not done:
         return []
-    longest = max(done, key=lambda i: (records[i]["size"], -i))
-    order = [longest] + [i for i in rng.permutation(done) if i != longest]
+    served = {}
+    for i in done:
+        served.setdefault(records[i]["stats"].get("replica", 0), []).append(i)
+    longest = [max(ix, key=lambda i: (records[i]["size"], -i))
+               for _r, ix in sorted(served.items())]
+    order = longest + [i for i in rng.permutation(done) if i not in longest]
     out, have = [], 0
-    for i in order:
+    for n, i in enumerate(order):
         out.append(records[i])
         have += records[i]["size"]
-        if have >= want:
+        if have >= want and n >= len(longest) - 1:
             break
     return out
+
+
+class Router:
+    """One replica of the index on each of ``devs`` behind the program's
+    router, ``repro.dist.replica.ReplicaSet``, placed as ``launch/serve.py``
+    places its replicas: called with a request, it routes it to the
+    least-loaded replica and returns the answer once it is ready on that
+    replica's chip, with the replica's number in its stats; the caller
+    copies it to the host.
+
+    ``index`` was built on the first device; each other replica gets its
+    copy with ``jax.device_put(index, device)``, one at a time, plans its own
+    Searcher on it and calls it with every size in ``warm``, so no worker
+    compiles in the window.  As in serve, requests go in as host arrays:
+    the Searcher uploads and pads them where JAX puts them by default.
+    The router logs one request row per request served to a
+    ``Telemetry`` of its own.
+    """
+
+    def __init__(self, devs, index, make_searcher, setup: dict, system,
+                 warm: list):
+        import jax
+
+        from repro.dist.replica import ReplicaSet
+        from repro.runtime.telemetry import Telemetry
+
+        self.searchers = []
+        self.telemetry = Telemetry()
+
+        def make_replica(r):
+            # serve puts a copy on the first chip too, beside the built
+            # index it keeps; the built index is the first replica here
+            idx = index if r == 0 else jax.device_put(index, devs[r])
+            searcher = make_searcher(idx)
+            serve = searcher if system is None else system(searcher, {
+                **setup, "index": idx, "replica": r, "device": devs[r]})
+            for size in warm:
+                jax.block_until_ready(serve(setup["pool"].queries[:size]).ids)
+            self.searchers.append(searcher)
+
+            def run(queries):
+                res = serve(queries)
+                jax.block_until_ready(res.ids)
+                return r, res
+
+            return run
+
+        self.replicas = ReplicaSet(make_replica, len(devs),
+                                   telemetry=self.telemetry)
+
+    def __call__(self, queries):
+        r, res = self.replicas.submit(
+            queries, queries=int(queries.shape[0])).result()
+        return SimpleNamespace(scores=res.scores, ids=res.ids,
+                               stats={**res.stats, "replica": r})
+
+    def close(self) -> list:
+        """Stops the replicas once every request has been served; returns
+        the request rows the router logged."""
+        self.replicas.close()
+        return [e for e in self.telemetry.events if e["type"] == "request"]
 
 
 def build(cell, keys, devs):
@@ -142,6 +223,12 @@ def run_cell(cell, seed: int, seconds: float, with_trace: bool, *,
 
     cfg, mix = cell.config, cell.mix
     n, d = int(cfg["n"]), int(cfg["d"])
+    layout, clients = mix.get("layout", "single"), int(mix.get("clients", 1))
+    if layout not in ("single", "replicas"):
+        raise ValueError(f"{cell.name}: unknown layout {layout!r}; "
+                         "have 'single' and 'replicas'")
+    # one replica on each of the cell's chips, or one Searcher on the first
+    replicas = len(devs) if layout == "replicas" else 1
     keys = dict(zip(("data", "corpus", "queries", "build"),
                     jax.random.split(data.key_from_seed(seed), 4)))
     if "seed" in cfg["data"]:
@@ -170,11 +257,18 @@ def run_cell(cell, seed: int, seconds: float, with_trace: bool, *,
               else None)
     setup = {"gen": gen, "consts": consts, "keys": keys, "cell": cell,
              "index": index, "make_searcher": make_searcher,
-             "coarse": coarse, "pool": pool}
-    searcher = make_searcher(index)
-    serve = searcher if system is None else system(searcher, setup)
-    for size in warm:
-        jax.block_until_ready(serve(pool.queries[:size]).ids)
+             "coarse": coarse, "pool": pool, "replica": 0,
+             "replicas": replicas, "device": devs[0]}
+    router = None
+    if layout == "single" and clients == 1:
+        searcher = make_searcher(index)
+        serve = searcher if system is None else system(searcher, setup)
+        for size in warm:
+            jax.block_until_ready(serve(pool.queries[:size]).ids)
+    else:
+        serve = router = Router(devs[:replicas], index, make_searcher, setup,
+                                system, warm)
+        searcher = router.searchers[0]
     del index
     gc.collect()
     phase("plan and warm-up")
@@ -182,6 +276,9 @@ def run_cell(cell, seed: int, seconds: float, with_trace: bool, *,
     # what the chip holds while it serves: the bytes in use once warm,
     # plus the largest temporary space one of the cell's buckets needs
     held = _device_memory(devs, "bytes_in_use")
+    print("[bench] bytes in use on each chip: "
+          + ", ".join(str((d.memory_stats() or {}).get("bytes_in_use"))
+                      for d in devs), file=log, flush=True)
     temp = max(int(searcher.lower(b).compile().memory_analysis()
                    .temp_size_in_bytes)
                for b in {searcher.buckets_for(size)[0] for size in warm})
@@ -190,16 +287,25 @@ def run_cell(cell, seed: int, seconds: float, with_trace: bool, *,
 
     compiles = _CompileCounter()
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if with_trace else None
-    cycles = traffic.cycles(mix, rng_traffic)
+    if clients == 1:
+        cycles = traffic.cycles(mix, rng_traffic)
+    else:
+        # each client its own order of the same sizes
+        cycles = [traffic.cycles(mix, np.random.default_rng([seed, 1, c]))
+                  for c in range(clients)]
     setup_s = time.perf_counter() - t_start
     if trace_dir:
         jax.profiler.start_trace(trace_dir)
     compiles.on = True
     t_open = time.perf_counter()
-    records = loop.closed(serve, pool, cycles, seconds)
+    if clients == 1:
+        records = loop.closed(serve, pool, cycles, seconds)
+    else:
+        records = loop.closed_pool(serve, pool, cycles, seconds)
     compiles.on = False
     if trace_dir:
         jax.profiler.stop_trace()
+    replica_log = None if router is None else router.close()
 
     build_peak = _device_memory(devs, "peak_bytes_in_use")
     trace_rec = reduced = None
@@ -222,8 +328,9 @@ def run_cell(cell, seed: int, seconds: float, with_trace: bool, *,
         records=[{k: v for k, v in r.items() if k != "result"}
                  for r in records],
         n=n, d=d, row_bytes=row_bytes, device_kind=devs[0].device_kind,
-        trace=trace_rec, reduced=reduced)
-    del records, serve, searcher, picks, rec, setup, coarse
+        trace=trace_rec, reduced=reduced, replicas=replicas,
+        replica_log=replica_log)
+    del records, serve, searcher, router, picks, rec, setup, coarse
     gc.collect()
     phase("window and trace reading")
 
@@ -233,7 +340,7 @@ def run_cell(cell, seed: int, seconds: float, with_trace: bool, *,
                                np.concatenate(scores))
         # one fixed sample shape per cell, so the reference compiles once
         real = q_rows.shape[0]
-        width = int(mix["check"]) + max(warm)
+        width = max(int(mix["check"]) + max(warm), replicas * max(warm))
         q_pad = np.resize(q_rows, (width, d))
         id_pad = np.resize(ids, (width, ids.shape[1]))
         ref = reference.exact(gen, keys["corpus"], consts, n, cfg["metric"],

@@ -5,8 +5,8 @@ quantiles of the mix's distribution; the seed only orders them (each
 cycle shuffled anew).  So two seeds do the same work in another order,
 and the set of shapes to warm up is fixed.
 
-Mix file keys (``bench/traffic/<name>.json``); one client sends each
-request when the last one is answered (``loop.closed``):
+Mix file keys (``bench/traffic/<name>.json``); each client sends its
+next request when its last one is answered:
 
 batch       queries per request:
             ``{"dist": "pareto", "alpha": a, "min": lo, "max": hi}``
@@ -16,10 +16,20 @@ cycle       how many sizes one shuffled cycle holds; a closed loop ends
 k           neighbours per query
 pool        distinct queries drawn for the mix
 check       queries whose answers are compared with the reference
+layout      how the cell's chips hold the index: ``single`` (default), one
+            Searcher on the first chip; ``replicas``, a one-chip replica on
+            each of the cell's chips behind the program's router
+            (``repro.dist.replica.ReplicaSet``)
+clients     closed-loop clients, each on a thread of its own (default 1);
+            more than one are served through the router
+
+At the defaults one client on the calling thread calls the Searcher
+directly (``loop.closed``), with no router and no thread in between.
 """
 
 from __future__ import annotations
 
+import threading
 from typing import Iterator
 
 import numpy as np
@@ -62,10 +72,13 @@ class QueryPool:
     def __init__(self, queries: np.ndarray):
         self.queries = np.ascontiguousarray(queries, np.float32)
         self._next = 0
+        self._lock = threading.Lock()
 
     def take(self, size: int) -> tuple[int, np.ndarray]:
+        """The next ``size`` queries and where they start; clients on
+        several threads get disjoint slices."""
         n = self.queries.shape[0]
-        start = self._next
-        idx = (start + np.arange(size)) % n
-        self._next = (start + size) % n
-        return start, self.queries[idx]
+        with self._lock:
+            start = self._next
+            self._next = (start + size) % n
+        return start, self.queries[(start + np.arange(size)) % n]

@@ -27,11 +27,10 @@ def copy_bench(dest: str) -> str:
     return dest
 
 
-@pytest.fixture
-def tiny_root(tmp_path):
-    """The benchmark with every configuration cut to a few thousand rows
-    and every mix to a small pool and check sample."""
-    root = copy_bench(str(tmp_path))
+def tiny_copy(dest: str) -> str:
+    """A copy of the benchmark with every configuration cut to a few
+    thousand rows and every mix to a small pool and check sample."""
+    root = copy_bench(dest)
     for name, changes in TINY.items():
         path = os.path.join(root, "bench", "configs", name + ".json")
         with open(path) as f:
@@ -51,3 +50,8 @@ def tiny_root(tmp_path):
         with open(path, "w") as f:
             json.dump(mix, f)
     return root
+
+
+@pytest.fixture
+def tiny_root(tmp_path):
+    return tiny_copy(str(tmp_path))
